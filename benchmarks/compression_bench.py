@@ -283,7 +283,6 @@ def run_pod_wire(*, d: int, fraction: float, reps: int):
     from jax.sharding import PartitionSpec as P
 
     from repro.core.dist import CompressedAggregation
-    from repro.launch import compat
     from repro.launch.mesh import make_test_mesh
     from repro.launch.steps import configure_agg
 
@@ -308,10 +307,10 @@ def run_pod_wire(*, d: int, fraction: float, reps: int):
             direction, _ = agg.aggregate(g, state, jax.random.PRNGKey(0))
             return jax.tree.map(lambda x: x[None], direction)
 
-        mapped = compat.shard_map(round_fn, mesh=mesh, in_specs=(specs,),
-                                  out_specs=specs,
-                                  axis_names=set(mesh.axis_names),
-                                  check_vma=False)
+        mapped = jax.shard_map(round_fn, mesh=mesh, in_specs=(specs,),
+                               out_specs=specs,
+                               axis_names=set(mesh.axis_names),
+                               check_vma=False)
         sec = bench(mapped, grads, reps=reps)
         local = {"w": jnp.zeros((d // 2,), jnp.float32)}  # per-device block
         wire = agg.wire_bytes_per_round(local)
@@ -344,7 +343,6 @@ def run_wire_packed(*, d: int, fraction: float, reps: int):
 
     from repro.compression.backend import WIRE_DTYPES
     from repro.core.dist import CompressedAggregation
-    from repro.launch import compat
     from repro.launch.mesh import make_test_mesh
     from repro.launch.steps import configure_agg
 
@@ -370,10 +368,10 @@ def run_wire_packed(*, d: int, fraction: float, reps: int):
             direction, _ = agg.aggregate(g, state, jax.random.PRNGKey(0))
             return jax.tree.map(lambda x: x[None], direction)
 
-        mapped = compat.shard_map(round_fn, mesh=mesh, in_specs=(specs,),
-                                  out_specs=specs,
-                                  axis_names=set(mesh.axis_names),
-                                  check_vma=False)
+        mapped = jax.shard_map(round_fn, mesh=mesh, in_specs=(specs,),
+                               out_specs=specs,
+                               axis_names=set(mesh.axis_names),
+                               check_vma=False)
         sec = bench(mapped, grads, reps=reps)
         wire = agg.wire_bytes_per_round(local)
         out[wd] = {"step_s": sec, "intra_pod": wire["intra_pod"]}

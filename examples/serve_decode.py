@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_NAMES, get_config, reduced
-from repro.launch import compat
 from repro.launch import steps
 from repro.launch.mesh import make_test_mesh
 from repro.models import transformer as T
@@ -48,7 +47,7 @@ def main():
             key, (args.batch, cfg.encoder_seq, cfg.d_model), cfg.dtype)
 
     serve, lower_args = steps.make_serve_step(cfg, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, cache = T.prefill(params, batch, cfg, cache_len=cache_len)
         jitted, (psh, csh, tsh) = lower_args(
             jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params),

@@ -25,7 +25,6 @@ from repro.core.dist import CompressedAggregation
 from repro.data.pipeline import make_batch_stream, shared_slots_for_step
 from repro.data.reshuffle import ReshuffleSampler
 from repro.data.tokens import synthetic_token_batches
-from repro.launch import compat
 from repro.launch import steps
 from repro.launch.mesh import make_test_mesh, num_clients
 from repro.models.config import ArchConfig
@@ -82,7 +81,7 @@ def main():
     sampler = ReshuffleSampler(m, n_batches,
                                mode="rr_shared" if slotted else "rr", seed=1)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg, m), shardings)
         key = jax.random.key(1)

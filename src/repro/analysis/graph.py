@@ -137,7 +137,7 @@ def _trace_step(cfg, mesh, method: str, *, elastic: bool = False,
     import jax.numpy as jnp
 
     from repro.core.dist import CompressedAggregation
-    from repro.launch import compat, steps
+    from repro.launch import steps
     from repro.launch.mesh import num_clients
 
     agg0 = CompressedAggregation(method=method, wire="shared",
@@ -158,7 +158,7 @@ def _trace_step(cfg, mesh, method: str, *, elastic: bool = False,
         extra.append(jax.ShapeDtypeStruct((1,), jnp.int32))
     if elastic:
         extra.append(jax.ShapeDtypeStruct((m,), jnp.float32))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         traced = jitted.trace(abstract, batch, key, *extra)
         lowered = jitted.lower(abstract, batch, key, *extra)
     return traced, lowered, abstract, agg

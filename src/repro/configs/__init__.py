@@ -42,6 +42,20 @@ def get_config(name: str) -> ArchConfig:
     return mod.CONFIG
 
 
+def get_chip_config(name: str) -> ArchConfig:
+    """The arch's chip-share cut (published widths, depth cut to one TPU
+    v5e chip's share); only archs whose config file defines CHIP_SHARE
+    have one."""
+    get_config(name)
+    mod = importlib.import_module(f"repro.configs.{_MODULES[name]}")
+    if not hasattr(mod, "CHIP_SHARE"):
+        have = [n for n in ARCH_NAMES if hasattr(importlib.import_module(
+            f"repro.configs.{_MODULES[n]}"), "CHIP_SHARE")]
+        raise ValueError(f"{name!r} has no chip-share cut yet; archs with "
+                         f"one: {have}")
+    return mod.CHIP_SHARE
+
+
 def all_configs() -> dict[str, ArchConfig]:
     return {n: get_config(n) for n in ARCH_NAMES}
 
@@ -86,6 +100,7 @@ __all__ = [
     "INPUT_SHAPES",
     "InputShape",
     "all_configs",
+    "get_chip_config",
     "get_config",
     "input_specs",
     "reduced",

@@ -180,6 +180,11 @@ class FleetRunner:
             meta["data_store"] = self._pager.data.spec()
         return meta
 
+    @property
+    def shift_field(self) -> str:
+        """The TrainState field that holds the cohort's per-client state."""
+        return self._shift_field
+
     def _device_shifts(self, state):
         return getattr(state, self._shift_field)
 

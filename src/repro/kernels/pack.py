@@ -21,9 +21,10 @@ lattice (DESIGN.md §3.13):
                   multiply).
   unpack_reduce   the fused unpack-accumulate half of the packed collective:
                   all-gathered (R, Kp, D) bytes + (R, K, 1) scales -> the
-                  f32 mean slab in ONE kernel — grid over ranks, each step
-                  decodes rank r's slab and accumulates into the same output
-                  block, the last step divides by R. Accumulation is in rank
+                  f32 mean slab in ONE kernel — grid (row blocks, R) with
+                  the rank innermost: each step decodes rank r's row block
+                  and accumulates into the resident output block, the last
+                  rank divides by R. Accumulation is in rank
                   order, which bit-matches ``lax.pmean`` of the decoded
                   slabs on the meshes we run (R a power of two; the division
                   by R is then exact either way).
@@ -31,10 +32,15 @@ lattice (DESIGN.md §3.13):
 Bias representation needs 2L+1 <= 256 byte values (L <= 127 for int8,
 L <= 7 for the nibble lanes); `core.dist` validates the caps. The uniforms
 are generated OUTSIDE the kernel (shared wire key + WIRE_QUANT_SALT) and
-streamed in, like kernels/qsgd.py. Block shapes are tuned for interpret
-mode on CPU (one grid step; see the qsgd.py note); on Mosaic the uint8
-blocks want >= (32, 128) tiles — revisit the row blocking before enabling
-packed wires on real TPUs.
+streamed in, like kernels/qsgd.py.
+
+Blocking (`_row_block`): every kernel tiles the slab's rows, so its VMEM use
+is bounded by the row block and the lane width D, never by K or the rank
+count R. A packed uint8 block holds a multiple of 32 rows (the uint8 tile is
+(32, 128)); for nibble slabs that is 64 value rows per block, so every block
+holds whole row pairs. Rows are padded up to a whole number of blocks inside
+each wrapper and the padding is sliced off again: the shapes callers see
+(and the bytes the wire moves) do not depend on the blocking.
 """
 from __future__ import annotations
 
@@ -90,7 +96,7 @@ def _unpack_kernel(p_ref, s_ref, o_ref, *, levels: int, nibble: bool):
 
 def _unpack_reduce_kernel(p_ref, s_ref, o_ref, *, levels: int, nibble: bool,
                           ranks: int):
-    r = pl.program_id(0)
+    r = pl.program_id(1)
     contrib = _decode(p_ref[0], s_ref[0], levels, nibble)
 
     @pl.when(r == 0)
@@ -106,22 +112,27 @@ def _unpack_reduce_kernel(p_ref, s_ref, o_ref, *, levels: int, nibble: bool,
         o_ref[...] = o_ref[...] / float(ranks)
 
 
-def _pad_rows(x):
-    pad = (-x.shape[0]) % BLOCK_ROWS
+# bytes of one f32 (rows, D) operand block; with double buffering and the
+# kernels' f32 temporaries a grid step stays well inside the scoped VMEM
+_BLOCK_BYTES = 1 << 20
+
+
+def _row_block(kp: int, d: int, nibble: bool) -> int:
+    """Value rows per grid step: whole packed uint8 tiles of 32 rows (64
+    value rows when two rows share a byte), as many as fit _BLOCK_BYTES at
+    lane width d, and no more than the slab needs."""
+    align = 64 if nibble else 32
+    rows = max(align, (_BLOCK_BYTES // (4 * d)) // align * align)
+    return min(rows, -(-kp // align) * align)
+
+
+def _pad_axis(x, to: int, axis: int):
+    pad = to - x.shape[axis]
     if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
+        widths = [(0, 0)] * x.ndim
+        widths[axis] = (0, pad)
+        x = jnp.pad(x, widths)
     return x
-
-
-def _row_blocking(row_blocks: int, interpret: bool) -> int:
-    """Row-groups per grid step: everything at once in interpret mode (one
-    emulated grid step, see kernels/qsgd.py), else a small exact divisor."""
-    if interpret:
-        return row_blocks
-    br = min(4, row_blocks)
-    while row_blocks % br:
-        br //= 2
-    return max(br, 1)
 
 
 @partial(jax.jit, static_argnames=("levels", "nibble", "interpret"))
@@ -132,16 +143,16 @@ def pack_slab(vals: jax.Array, u: jax.Array, *, levels: int,
     nibble=True, (Kp/2, D). Padding rows quantize to the zero byte."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    vals = _pad_rows(vals.astype(jnp.float32))
-    u = _pad_rows(u)
-    kp, d = vals.shape
-    rb = kp // BLOCK_ROWS
-    br = _row_blocking(rb, interpret)
-    rows = br * BLOCK_ROWS
+    k, d = vals.shape
+    kp = k + (-k) % BLOCK_ROWS
+    rows = _row_block(kp, d, nibble)
+    kt = -(-kp // rows) * rows
+    vals = _pad_axis(vals.astype(jnp.float32), kt, 0)
+    u = _pad_axis(u, kt, 0)
     prows = rows // 2 if nibble else rows
-    return pl.pallas_call(
+    packed, scales = pl.pallas_call(
         partial(_pack_kernel, levels=levels, nibble=nibble),
-        grid=(rb // br,),
+        grid=(kt // rows,),
         in_specs=[
             pl.BlockSpec((rows, d), lambda i: (i, 0)),
             pl.BlockSpec((rows, d), lambda i: (i, 0)),
@@ -151,11 +162,12 @@ def pack_slab(vals: jax.Array, u: jax.Array, *, levels: int,
             pl.BlockSpec((rows, 1), lambda i: (i, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((kp // 2 if nibble else kp, d), jnp.uint8),
-            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((kt // 2 if nibble else kt, d), jnp.uint8),
+            jax.ShapeDtypeStruct((kt, 1), jnp.float32),
         ),
         interpret=interpret,
     )(vals, u)
+    return packed[:kp // 2 if nibble else kp], scales[:kp]
 
 
 @partial(jax.jit, static_argnames=("levels", "n_rows", "nibble", "interpret"))
@@ -167,21 +179,21 @@ def unpack_slab(packed: jax.Array, scales: jax.Array, *, levels: int,
         interpret = jax.default_backend() == "cpu"
     kp = scales.shape[0]
     d = packed.shape[1]
-    rb = kp // BLOCK_ROWS
-    br = _row_blocking(rb, interpret)
-    rows = br * BLOCK_ROWS
+    rows = _row_block(kp, d, nibble)
+    kt = -(-kp // rows) * rows
     prows = rows // 2 if nibble else rows
     out = pl.pallas_call(
         partial(_unpack_kernel, levels=levels, nibble=nibble),
-        grid=(rb // br,),
+        grid=(kt // rows,),
         in_specs=[
             pl.BlockSpec((prows, d), lambda i: (i, 0)),
             pl.BlockSpec((rows, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((kp, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((kt, d), jnp.float32),
         interpret=interpret,
-    )(packed, scales)
+    )(_pad_axis(packed, kt // 2 if nibble else kt, 0),
+      _pad_axis(scales, kt, 0))
     return out[:n_rows]
 
 
@@ -194,17 +206,21 @@ def unpack_reduce(packed: jax.Array, scales: jax.Array, *, levels: int,
     kernel (the receive half of the packed collective)."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    r, prows, d = packed.shape
+    r, _, d = packed.shape
     kp = scales.shape[1]
+    rows = _row_block(kp, d, nibble)
+    kt = -(-kp // rows) * rows
+    prows = rows // 2 if nibble else rows
     out = pl.pallas_call(
         partial(_unpack_reduce_kernel, levels=levels, nibble=nibble, ranks=r),
-        grid=(r,),
+        grid=(kt // rows, r),
         in_specs=[
-            pl.BlockSpec((1, prows, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, kp, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, prows, d), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((1, rows, 1), lambda i, j: (j, i, 0)),
         ],
-        out_specs=pl.BlockSpec((kp, d), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((kp, d), jnp.float32),
+        out_specs=pl.BlockSpec((rows, d), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((kt, d), jnp.float32),
         interpret=interpret,
-    )(packed, scales)
+    )(_pad_axis(packed, kt // 2 if nibble else kt, 1),
+      _pad_axis(scales, kt, 1))
     return out[:n_rows]

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import telemetry
-from repro.launch import compat
 
 from repro.configs import (
     ARCH_NAMES,
@@ -66,7 +65,7 @@ def _compile_one(cfg, shape, mesh, agg, *, remat, unroll: bool,
         # the buffered-async wire weights vector (elastic step only)
         extra = ((jax.ShapeDtypeStruct((num_clients(mesh),), jnp.float32),)
                  if elastic else ())
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if agg.rule.slotted:  # per-slot methods take the slot vector
                 slots = jax.ShapeDtypeStruct((local_steps,), jnp.int32)
                 lowered = jitted.lower(abstract, batch, key, slots, *extra)
@@ -81,7 +80,7 @@ def _compile_one(cfg, shape, mesh, agg, *, remat, unroll: bool,
                 salts.root_key(0, salts.PARAMS_KEY_SALT), cfg)
         )
         jitted = lower_args(params_abs, specs["batch"])
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_abs, specs["batch"])
     else:  # decode
         serve, lower_args = steps.make_serve_step(cfg, mesh, unroll=unroll)
@@ -90,7 +89,7 @@ def _compile_one(cfg, shape, mesh, agg, *, remat, unroll: bool,
                 salts.root_key(0, salts.PARAMS_KEY_SALT), cfg)
         )
         jitted, _ = lower_args(params_abs, specs["cache"], specs["tokens"])
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_abs, specs["cache"],
                                    specs["tokens"], specs["pos"])
     return lowered.compile()

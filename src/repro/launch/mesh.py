@@ -28,6 +28,24 @@ def make_production_mesh(*, multi_pod: bool = False):
     return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
 
 
+def make_attached_mesh(pods: int = 1):
+    """Mesh over every attached device, one federated client per device:
+    ("data", "model") = (n, 1), or ("pod", "data", "model") =
+    (pods, n // pods, 1) for the two-level wire. On one chip that is m = 1,
+    on a four-chip host m = 4."""
+    import jax
+    from jax.sharding import Mesh
+
+    devices = jax.devices()
+    n = len(devices)
+    if pods == 1:
+        return Mesh(np.asarray(devices).reshape(n, 1), ("data", "model"))
+    if n % pods:
+        raise ValueError(f"{n} devices do not split into {pods} pods")
+    return Mesh(np.asarray(devices).reshape(pods, n // pods, 1),
+                ("pod", "data", "model"))
+
+
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for unit tests on forced host devices."""
     import jax

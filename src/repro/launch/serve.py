@@ -2,27 +2,21 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-7b --tokens 16
 
-CPU runs the reduced config on the 8-device test mesh; --production-mesh
-builds the pod mesh with the full config (requires hardware / the dry-run's
-forced host devices).
+It runs the reduced config on a mesh of the attached devices (set no XLA
+flags here; a CPU run forces host devices on its command line);
+--production-mesh builds the pod mesh with the full config (requires
+hardware / the dry-run's forced host devices).
 """
-import os
-
-if "--production-mesh" not in os.sys.argv:
-    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import argparse
 import time
 
 import jax
-
-from repro.launch import compat
 import jax.numpy as jnp
 
 from repro.configs import ARCH_NAMES, get_config, reduced
 from repro.core import salts
 from repro.launch import steps
-from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.launch.mesh import make_attached_mesh, make_production_mesh
 from repro.models import transformer as T
 
 
@@ -41,7 +35,7 @@ def main():
         mesh = make_production_mesh(multi_pod=args.multi_pod)
         cfg = get_config(args.arch)
     else:
-        mesh = make_test_mesh((4, 2), ("data", "model"))
+        mesh = make_attached_mesh()
         cfg = reduced(get_config(args.arch), seq=max(64, 2 * args.prompt_len))
     key = salts.root_key(0, salts.SERVE_KEY_SALT)
     params = T.init_params(key, cfg)
@@ -57,7 +51,7 @@ def main():
             key, (args.batch, cfg.encoder_seq, cfg.d_model), cfg.dtype)
 
     serve, lower_args = steps.make_serve_step(cfg, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, cache = T.prefill(params, batch, cfg, cache_len=cache_len)
         jitted, (psh, csh, tsh) = lower_args(
             jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params),

@@ -7,10 +7,7 @@
     batch, "model" tensor parallelism compiler-managed);
   - the WIRE — compression, shift updates, and the sparse collectives — runs
     in a fully-manual `jax.shard_map` over every mesh axis, so the paper's
-    per-client semantics are explicit and nothing depends on the partial-auto
-    shard_map path (which miscompiles on the pinned 0.4.x JAX: GSPMD emits
-    malformed tile assignments for replicated inputs of a partial-manual
-    region — see ROADMAP "launch layer" history);
+    per-client semantics are explicit;
   - `CompressedAggregation` (core/dist.py) is hierarchical: the "data" axis
     inside a pod runs the kernelized shared Rand-block psum and the "pod"
     axis runs a second, independently-keyed compressed exchange with its own
@@ -45,7 +42,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import salts
 from repro.core.dist import CompressedAggregation, DianaState
-from repro.launch import compat, sharding
+from repro.launch import sharding
 from repro.launch.mesh import (
     client_axes as _client_axes,
     data_axes as _data_axes,
@@ -321,9 +318,9 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
 
     def manual(f, in_specs, out_specs):
         """Fully-manual shard_map (every axis manual) — the wire region."""
-        return compat.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, axis_names=all_axes,
-                                check_vma=False)
+        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, axis_names=all_axes,
+                             check_vma=False)
 
     # spec trees matching the (possibly None) state fields; slotted tables
     # carry a replicated n_slots axis after the client/pod axis

@@ -3,13 +3,18 @@
     PYTHONPATH=src python -m repro.launch.train --arch stablelm-1.6b \
         --steps 100 --agg diana --fraction 0.02 [--production-mesh]
 
-On CPU (this container) it runs the REDUCED config of the chosen arch on an
-8-host-device (data=4, model=2) mesh; on a real pod pass --production-mesh
-to build the 16x16 (or 2x16x16 with --multi-pod) mesh and the full config.
-Every piece is the production path: shard_map per-client gradients, the
-paper's compressed wire, DIANA shifts, the epoch-indexed RR batch stream
-(`data.pipeline`, DESIGN.md §3.7) with double-buffered prefetch, and
-cursor-checkpointed resume (`--resume` bit-reproduces the data stream).
+The mesh is built from the attached devices, one federated client per
+device (`make_attached_mesh`): m = 1 on one TPU chip, m = 4 on a four-chip
+host. On a TPU it runs the arch's chip-share config (published widths,
+depth cut to one chip's share, `configs.get_chip_config`) with full remat;
+elsewhere it runs the `reduced()` config, without remat, for rehearsals.
+This module sets no XLA flags: a CPU rehearsal forces its host devices on
+its own command line (README.md). `--production-mesh` builds the 16x16 (or
+2x16x16 with --multi-pod) mesh with the full config. Every piece is the
+production path: per-client gradients, the paper's compressed wire, DIANA
+shifts, the epoch-indexed RR batch stream (`data.pipeline`, DESIGN.md §3.7)
+with double-buffered prefetch, and cursor-checkpointed resume (`--resume`
+bit-reproduces the data stream).
 
 `--clients C` (with C > the mesh client count) switches to the FLEET path
 (DESIGN.md §3.9): each round samples a cohort of mesh-rank-many clients
@@ -22,15 +27,10 @@ bit-reproduces an uninterrupted one. With C equal to the mesh client count
 the fleet path bit-matches this file's full-participation loop.
 """
 import os
-
-if "--production-mesh" not in os.sys.argv:
-    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import argparse
+from typing import Any, NamedTuple
 
 import jax
-
-from repro.launch import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -41,7 +41,7 @@ from repro.checkpoint.io import (
     restore_fleet_checkpoint,
     save_fleet_checkpoint,
 )
-from repro.configs import ARCH_NAMES, get_config, reduced
+from repro.configs import ARCH_NAMES, get_chip_config, get_config, reduced
 from repro.core import salts
 from repro.core.dist import CompressedAggregation
 from repro.data.paging import ClientDataStore, LookaheadPager
@@ -59,7 +59,13 @@ from repro.fleet import (
     FleetRunner,
 )
 from repro.launch import steps
-from repro.launch.mesh import make_production_mesh, make_test_mesh, num_clients
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import (
+    make_attached_mesh,
+    make_production_mesh,
+    num_clients,
+)
+from repro.models.config import ArchConfig
 
 
 def stub_modalities(cfg, m: int, n_batches: int, b: int, *, seed: int = 0):
@@ -102,8 +108,7 @@ def fleet_is_async(args) -> bool:
             or chaos.store_fail > 0)
 
 
-def run_fleet(args, cfg, mesh, agg, m, n_batches, b,
-              jitted, abstract, shardings, batch_sh):
+def run_fleet(args, tr, b, callback=None):
     """The fleet (partial-participation) loop: C-client population, cohort
     of m mesh ranks per round, host state store (DESIGN.md §3.9).
 
@@ -115,6 +120,9 @@ def run_fleet(args, cfg, mesh, agg, m, n_batches, b,
     lookahead window, not the population (DESIGN.md §3.11). Batches are
     bit-identical either way.
     """
+    cfg, mesh, agg, m, n_batches = tr.cfg, tr.mesh, tr.agg, tr.m, tr.n_batches
+    jitted, abstract, shardings, batch_sh = (tr.jitted, tr.abstract,
+                                             tr.shardings, tr.batch_sh)
     C = args.clients
     data = {"tokens": np.asarray(synthetic_token_batches(
         vocab=cfg.vocab, seq_len=args.seq, batch=b,
@@ -183,7 +191,7 @@ def run_fleet(args, cfg, mesh, agg, m, n_batches, b,
         start_round = fm["round"]
 
     key = salts.root_key(0, salts.ROUNDS_KEY_SALT)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if args.resume:
             state = restore_fleet_checkpoint(
                 args.resume, abstract, shardings, store,
@@ -191,12 +199,7 @@ def run_fleet(args, cfg, mesh, agg, m, n_batches, b,
             print(f"resumed {args.resume} at round {start_round} "
                   f"(fleet epoch {fm['fleet_epoch']})")
         else:
-            state = jax.device_put(
-                steps.init_train_state(
-                    salts.root_key(0, salts.PARAMS_KEY_SALT), cfg, agg, m,
-                    optimizer=args.optimizer, mesh=mesh,
-                    local_steps=args.local_steps),
-                shardings)
+            state = init_state(args, tr)
         if use_async:
             runner = AsyncFleetRunner(
                 jitted, abstract, shardings, batch_sh, agg=agg, mesh=mesh,
@@ -221,9 +224,15 @@ def run_fleet(args, cfg, mesh, agg, m, n_batches, b,
             unit="round", log_every=args.log_every, total=args.steps,
             start=start_round)
 
-        def log(t, _state, metrics):
+        def log(t, state, metrics):
             reporter.report(t, metrics, cohort=m)
+            if callback is not None:
+                callback(t, state, metrics)
 
+        # the store owns every client's state and the runner puts the
+        # cohort's rows on the device each round: holding the initial rows
+        # as well would keep a second per-client table on the device
+        state = state._replace(**{runner.shift_field: None})
         with runner:
             reporter.start()
             state = runner.run(state, key, args.steps - start_round,
@@ -236,6 +245,7 @@ def run_fleet(args, cfg, mesh, agg, m, n_batches, b,
                     data_store=None if pager is None else pager.data)
                 print(f"fleet checkpoint -> {args.checkpoint} "
                       f"(round {runner.round})")
+    return state
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -268,12 +278,18 @@ def build_parser() -> argparse.ArgumentParser:
                          "bit-pack quantized levels and all_gather the byte "
                          "lattice + f32 scale sideband (DESIGN.md §3.13); "
                          "'bf16' halves the psum lanes")
+    ap.add_argument("--wire-levels", type=int, default=None,
+                    help="stochastic-quantization levels of the shared-wire "
+                         "slab (packed wires default to their lane cap); "
+                         "'f32' with levels moves the same quantized payload "
+                         "as packed8/packed4 at 4 B/lane")
     # the paper's headline compression ratio (k/d ~= 0.02, Sec. 3) — must
     # stay in sync with the module-docstring example above
     ap.add_argument("--fraction", type=float, default=0.02)
     ap.add_argument("--pods", type=int, default=1,
-                    help="CPU test-mesh pods: >1 builds a (pods, 4/pods, 2) "
-                         "('pod','data','model') mesh for the two-level wire")
+                    help=">1 splits the attached devices into a (pods, "
+                         "n/pods, 1) ('pod','data','model') mesh for the "
+                         "two-level wire")
     ap.add_argument("--optimizer", choices=("sgd", "momentum", "adamw"),
                     default="sgd")
     ap.add_argument("--sampling", choices=("rr", "rr_once", "rr_shared", "wr"),
@@ -363,23 +379,40 @@ def telemetry_path(args) -> str | None:
     return None
 
 
-def main():
-    ap = build_parser()
-    args = ap.parse_args()
+class Trainer(NamedTuple):
+    """Everything `run` needs, built once from the CLI arguments."""
 
+    cfg: ArchConfig
+    mesh: Any
+    agg: CompressedAggregation
+    m: int
+    n_batches: int
+    remat: Any
+    jitted: Any
+    abstract: steps.TrainState
+    shardings: steps.TrainState
+    batch_sh: Any
+
+
+def build(ap: argparse.ArgumentParser, args) -> Trainer:
+    """Mesh, config, wire and compiled-step builder for one run."""
     if args.production_mesh:
         mesh = make_production_mesh(multi_pod=args.multi_pod)
-        cfg = get_config(args.arch)
-    elif args.pods > 1:
-        if args.pods not in (2, 4):
-            ap.error("--pods must be 1, 2 or 4 (the CPU test mesh has 4 "
-                     "client ranks to split into pods)")
-        mesh = make_test_mesh((args.pods, 4 // args.pods, 2),
-                              ("pod", "data", "model"))
-        cfg = reduced(get_config(args.arch), seq=args.seq)
+        cfg, remat, label = get_config(args.arch), "full", "full"
     else:
-        mesh = make_test_mesh((4, 2), ("data", "model"))
-        cfg = reduced(get_config(args.arch), seq=args.seq)
+        try:
+            mesh = make_attached_mesh(args.pods)
+        except ValueError as e:
+            ap.error(f"--pods {args.pods}: {e}")
+        if jax.default_backend() == "tpu":
+            try:
+                cfg = get_chip_config(args.arch)
+            except ValueError as e:
+                ap.error(str(e))
+            remat, label = "full", "chip-share"
+        else:
+            cfg = reduced(get_config(args.arch), seq=args.seq)
+            remat, label = False, "reduced"
     m = num_clients(mesh)
     n_batches = 8
     slotted = args.agg == "diana_rr"
@@ -408,41 +441,68 @@ def main():
     # rather than a (C/M)-inflated cohort estimate (DESIGN.md §3.10);
     # M == C gives 1.0, the exact full-participation form
     mean_scale = m / args.clients if args.clients is not None else 1.0
-    agg = CompressedAggregation(method=args.agg, wire=args.wire,
-                                fraction=args.fraction,
-                                n_slots=n_batches if slotted else 1,
-                                mean_scale=mean_scale,
-                                shift_dtype=jnp.float32,
-                                wire_dtype=args.wire_dtype)
-    remat = "full" if args.production_mesh else False
+    try:
+        agg = CompressedAggregation(method=args.agg, wire=args.wire,
+                                    fraction=args.fraction,
+                                    n_slots=n_batches if slotted else 1,
+                                    mean_scale=mean_scale,
+                                    shift_dtype=jnp.float32,
+                                    wire_dtype=args.wire_dtype,
+                                    wire_levels=args.wire_levels)
+    except ValueError as e:
+        ap.error(str(e))
     jitted, abstract, shardings, batch_sh = steps.make_train_step(
         cfg, mesh, agg=agg, lr=args.lr, eta=args.eta,
         local_steps=args.local_steps, remat=remat,
         optimizer=args.optimizer, elastic=fleet_is_async(args),
         debug_metrics=args.device_metrics)
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(abstract.params))
+    print(f"config={cfg.name}/{label} layers={cfg.num_layers} "
+          f"d_model={cfg.d_model} d_ff={cfg.d_ff} vocab={cfg.vocab} "
+          f"remat={remat}")
     print(f"arch={cfg.name} ({n_params/1e6:.1f}M params) clients={m} "
           f"agg={args.agg}/{args.wire}"
           + (f"/{args.wire_dtype}" if args.wire_dtype != "f32" else "")
+          + (f" levels={args.wire_levels}" if args.wire_levels else "")
           + f" k/d={args.fraction} "
           f"local_steps={args.local_steps} opt={args.optimizer}"
           + (f" fleet=C{args.clients}/{args.cohort_mode}"
              if args.clients is not None else ""))
+    return Trainer(cfg, mesh, agg, m, n_batches, remat, jitted, abstract,
+                   shardings, batch_sh)
 
+
+def init_state(args, tr: Trainer) -> steps.TrainState:
+    """Fresh state, built by one jitted program straight into the step's
+    shardings — no array is ever whole on one device first."""
+    init = jax.jit(
+        lambda key: steps.init_train_state(
+            key, tr.cfg, tr.agg, tr.m, optimizer=args.optimizer,
+            mesh=tr.mesh, local_steps=args.local_steps),
+        out_shardings=tr.shardings)
+    return init(salts.root_key(0, salts.PARAMS_KEY_SALT))
+
+
+def main(argv=None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
+    tr = build(ap, args)
     tpath = telemetry_path(args)
     if tpath is not None:
         telemetry.install(telemetry.MetricsSink(tpath))
         flags = {k: v for k, v in sorted(vars(args).items())
                  if isinstance(v, (str, int, float, bool, type(None)))}
-        agg_c = steps.configure_agg(agg, mesh, args.local_steps)
-        wire = agg_c.wire_bytes_per_round(abstract.params)
+        agg_c = steps.configure_agg(tr.agg, tr.mesh, args.local_steps)
+        wire = agg_c.wire_bytes_per_round(tr.abstract.params)
+        n_params = sum(int(np.prod(x.shape))
+                       for x in jax.tree.leaves(tr.abstract.params))
         telemetry.run_meta({
-            "argv": flags, "arch": cfg.name, "n_params": n_params,
-            "mesh_clients": m,
+            "argv": flags, "arch": tr.cfg.name, "n_params": n_params,
+            "mesh_clients": tr.m,
             "wire_bytes_per_round": {k: int(v) for k, v in wire.items()}})
     try:
-        return _run(args, cfg, mesh, agg, m, n_batches,
-                    jitted, abstract, shardings, batch_sh)
+        return run(args, tr)
     finally:
         sink = telemetry.active()
         if sink is not None:
@@ -455,13 +515,17 @@ def main():
                 print(f"trace -> {args.trace} ({n} trace events)")
 
 
-def _run(args, cfg, mesh, agg, m, n_batches,
-         jitted, abstract, shardings, batch_sh):
+def run(args, tr: Trainer, callback=None) -> steps.TrainState:
+    """The training loop (full participation, or the fleet with
+    --clients); returns the final state. `callback(t, state, metrics)`
+    runs after each step or round."""
+    cfg, mesh, agg, m, n_batches = tr.cfg, tr.mesh, tr.agg, tr.m, tr.n_batches
+    jitted, abstract, shardings, batch_sh = (tr.jitted, tr.abstract,
+                                             tr.shardings, tr.batch_sh)
     slotted = args.agg == "diana_rr"
     b = max(1, args.batch // m)
     if args.clients is not None:
-        return run_fleet(args, cfg, mesh, agg, m, n_batches, b,
-                         jitted, abstract, shardings, batch_sh)
+        return run_fleet(args, tr, b, callback)
     data = {"tokens": synthetic_token_batches(
         vocab=cfg.vocab, seq_len=args.seq, batch=b,
         num_batches=n_batches, num_clients=m, seed=0)}
@@ -482,17 +546,13 @@ def _run(args, cfg, mesh, agg, m, n_batches,
                 "different data stream")
         start_step = cursor["train_step"]
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if args.resume:
             state = restore_train_state(args.resume, abstract, shardings)
             print(f"resumed {args.resume} at step {start_step} "
                   f"(epoch {cursor['epoch']}, batch {cursor['step']})")
         else:
-            state = jax.device_put(
-                steps.init_train_state(
-                    salts.root_key(0, salts.PARAMS_KEY_SALT), cfg, agg, m,
-                    optimizer=args.optimizer, mesh=mesh,
-                    local_steps=args.local_steps), shardings)
+            state = init_state(args, tr)
         key = salts.root_key(0, salts.ROUNDS_KEY_SALT)
 
         if telemetry.enabled():
@@ -531,12 +591,15 @@ def _run(args, cfg, mesh, agg, m, n_batches,
                                       m * bits_per_client, round=t)
                     telemetry.round_metrics(t, metrics)
                 reporter.report(t, metrics)
+                if callback is not None:
+                    callback(t, state, metrics)
             if args.checkpoint:
                 save_pytree(args.checkpoint, jax.device_get(state),
                             step=int(state.step),
                             meta={"data_stream": stream.cursor_meta()})
                 print(f"checkpoint -> {args.checkpoint} "
                       f"(cursor {stream.cursor})")
+    return state
 
 
 if __name__ == "__main__":

@@ -25,15 +25,8 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:  # jax >= 0.5 exposes the batched ragged dot; older pins need the fallback
-    from jax.lax import RaggedDotDimensionNumbers, ragged_dot_general
-
-    _HAS_RAGGED_GENERAL = True
-except ImportError:  # pragma: no cover - exercised on the pinned 0.4.x JAX
-    RaggedDotDimensionNumbers = ragged_dot_general = None
-    _HAS_RAGGED_GENERAL = False
+from jax import custom_batching, lax
+from jax.lax import RaggedDotDimensionNumbers, ragged_dot_general
 
 from repro.models.config import ArchConfig
 from repro.models.layers import init_mlp, linear, mlp
@@ -58,29 +51,97 @@ _RAGGED_DN = RaggedDotDimensionNumbers(
     dot_dimension_numbers=(((2,), (1,)), ((), ())),
     lhs_ragged_dimensions=[1],
     rhs_group_dimensions=[0],
-) if _HAS_RAGGED_GENERAL else None
+)
 
 
-def _segment_ids(group_sizes, length):
-    """group_sizes (B, E) -> (B, length) expert id of each sorted token slot."""
-    ends = jnp.cumsum(group_sizes, axis=-1)  # (B, E)
-    slots = jnp.arange(length)
-    return jnp.sum(slots[None, :, None] >= ends[:, None, :], axis=-1)
+def _ragged_dot(lhs, rhs, group_sizes):
+    return ragged_dot_general(lhs, rhs, group_sizes, _RAGGED_DN,
+                              preferred_element_type=lhs.dtype)
 
 
+def _batch_all(axis_size, in_batched, *xs):
+    """Give every operand a leading batch axis (broadcast where absent)."""
+    return [x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
+            for x, b in zip(xs, in_batched)]
+
+
+def _fold_groups(a, lhs, group_sizes):
+    """(A, B, T, ...) tokens + (A, B, E) sizes -> (B, A*T, ...) + (B, A*E):
+    each row's tokens of the A batch members one after another, so the
+    groups run (member, expert) in order."""
+    b, t = lhs.shape[1], lhs.shape[2]
+    lhs = jnp.moveaxis(lhs, 0, 1).reshape((b, a * t) + lhs.shape[3:])
+    gs = jnp.moveaxis(group_sizes, 0, 1).reshape(b, -1)
+    return lhs, gs
+
+
+# Grouped matmul with a batching rule of its own. Under the train step's
+# per-client vmap the experts' weights are batched too, and JAX cannot batch
+# ragged_dot_general; the rule folds the client axis into the token axis
+# (each client's experts become groups of their own), so the batched call is
+# one ragged dot again. The backward pass is written with the same two
+# primitives, so it batches the same way.
+@custom_batching.custom_vmap
+def _grouped(lhs, rhs, group_sizes):
+    """lhs (B, T, K) tokens sorted by group per row, rhs (E, K, N),
+    group_sizes (B, E) -> (B, T, N)."""
+    return _ragged_dot(lhs, rhs, group_sizes)
+
+
+@_grouped.def_vmap
+def _grouped_vmap(axis_size, in_batched, lhs, rhs, group_sizes):
+    if not in_batched[1]:  # shared weights: the batch members are more rows
+        lhs, gs = _batch_all(axis_size, (in_batched[0], in_batched[2]),
+                             lhs, group_sizes)
+        out = _grouped(lhs.reshape((-1,) + lhs.shape[2:]), rhs,
+                       gs.reshape((-1,) + gs.shape[2:]))
+        return out.reshape((axis_size, -1) + out.shape[1:]), True
+    lhs, rhs, gs = _batch_all(axis_size, in_batched, lhs, rhs, group_sizes)
+    b, t = lhs.shape[1], lhs.shape[2]
+    lhs, gs = _fold_groups(axis_size, lhs, gs)
+    out = _grouped(lhs, rhs.reshape((-1,) + rhs.shape[2:]), gs)
+    return jnp.moveaxis(out.reshape(b, axis_size, t, -1), 1, 0), True
+
+
+@custom_batching.custom_vmap
+def _grouped_wgrad(lhs, g, group_sizes):
+    """Weight cotangent of `_grouped`: (E, K, N) = per group, the sum over
+    its tokens of lhs^T g."""
+    e = group_sizes.shape[-1]
+    rhs = jnp.zeros((e, lhs.shape[-1], g.shape[-1]), lhs.dtype)
+    _, vjp = jax.vjp(lambda r: _ragged_dot(lhs, r, group_sizes), rhs)
+    return vjp(g)[0]
+
+
+@_grouped_wgrad.def_vmap
+def _grouped_wgrad_vmap(axis_size, in_batched, lhs, g, group_sizes):
+    lhs, g, gs = _batch_all(axis_size, in_batched, lhs, g, group_sizes)
+    e = gs.shape[-1]
+    lhs_f, gs_f = _fold_groups(axis_size, lhs, gs)
+    g_f, _ = _fold_groups(axis_size, g, gs)
+    out = _grouped_wgrad(lhs_f, g_f, gs_f)
+    return out.reshape((axis_size, e) + out.shape[1:]), True
+
+
+@jax.custom_vjp
 def _ragged(lhs, rhs, group_sizes):
     """lhs (B, T, K_dim) x rhs (E, K_dim, N) grouped by row -> (B, T, N)."""
-    if _HAS_RAGGED_GENERAL:
-        return ragged_dot_general(lhs, rhs, group_sizes, _RAGGED_DN,
-                                  preferred_element_type=lhs.dtype)
-    # Dense einsum fallback for JAX pins without lax.ragged_dot_general: run
-    # every expert on every token, then select each token's expert by its
-    # group segment. Same result; E/k more FLOPs — matches what XLA's CPU
-    # group-loop lowering does anyway (see the roofline note above).
-    seg = _segment_ids(group_sizes, lhs.shape[1])  # (B, T)
-    onehot = jax.nn.one_hot(seg, rhs.shape[0], dtype=lhs.dtype)  # (B, T, E)
-    h = jnp.einsum("btd,edf->btef", lhs, rhs)
-    return jnp.einsum("btef,bte->btf", h, onehot).astype(lhs.dtype)
+    return _grouped(lhs, rhs, group_sizes)
+
+
+def _ragged_fwd(lhs, rhs, group_sizes):
+    return _grouped(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _ragged_bwd(res, g):
+    lhs, rhs, group_sizes = res
+    g = g.astype(lhs.dtype)
+    d_lhs = _grouped(g, jnp.swapaxes(rhs, 1, 2), group_sizes)
+    d_rhs = _grouped_wgrad(lhs, g, group_sizes)
+    return d_lhs, d_rhs.astype(rhs.dtype), None
+
+
+_ragged.defvjp(_ragged_fwd, _ragged_bwd)
 
 
 def moe_ffn(p, x, cfg: ArchConfig, *, return_aux: bool = False):

@@ -124,15 +124,25 @@ def test_rr_beats_sgd_late():
     assert sub_rr < sub_sgd
 
 
-def test_diana_rr_neighborhood_scales_as_gamma_squared():
-    """Thm 2: DIANA-RR's only residual term is 2*gamma^2*sigma_rad^2/mu —
-    halving gamma should shrink the floor ~4x (vs the O(gamma) floor of
-    Q-RR, Thm 1). We check the floor drops superlinearly in gamma and is
-    itself tiny in absolute terms."""
-    sub_g = PROBLEM.suboptimality(run("diana_rr", epochs=500, gamma=0.4 / PROBLEM.l_max).params["w"])
-    sub_g2 = PROBLEM.suboptimality(run("diana_rr", epochs=1000, gamma=0.2 / PROBLEM.l_max).params["w"])
-    assert sub_g < 1e-4          # deep convergence despite omega = 3
-    assert sub_g2 < sub_g / 2.5  # superlinear shrinkage with gamma
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_diana_rr_neighborhood_scales_as_gamma_squared(seed):
+    """Thm 2: DIANA-RR's only residual term is 2*gamma^2*sigma_rad^2/mu in
+    E||x_T - x*||^2 — halving gamma should shrink it ~4x (vs the O(gamma)
+    floor of Q-RR, Thm 1). Each stepsize runs epochs proportional to
+    1/gamma, so both runs sit on their floor, and the floor is read as the
+    float64 squared distance to x* (the float32 objective rounds at ~1e-7,
+    the size of the floor itself). We check the floor drops superlinearly
+    in gamma and is itself tiny in absolute terms, on every seed."""
+    def floor(gamma):
+        st = run("diana_rr", epochs=int(400 / gamma),
+                 gamma=gamma / PROBLEM.l_max, seed=seed)
+        w = np.asarray(st.params["w"], np.float64)
+        return float(np.sum((w - PROBLEM.x_star) ** 2)), w
+
+    dist_g, w_g = floor(1.0)
+    dist_g2, _ = floor(0.5)
+    assert PROBLEM.suboptimality(w_g) < 1e-4  # deep convergence, omega = 3
+    assert dist_g2 < dist_g / 2.5  # superlinear shrinkage with gamma
 
 
 def test_error_feedback_fixes_topk():

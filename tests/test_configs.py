@@ -5,6 +5,7 @@ from repro.configs import (
     ARCH_NAMES,
     INPUT_SHAPES,
     all_configs,
+    get_chip_config,
     get_config,
     reduced,
     shape_supported,
@@ -88,3 +89,28 @@ def test_vocab_padding():
     assert get_config("hymba-1.5b").padded_vocab() == 32016
     assert get_config("whisper-medium").padded_vocab() == 51872
     assert get_config("deepseek-67b").padded_vocab() == 102400  # already /16
+
+
+def test_chip_share_cuts_only_the_listed_keys():
+    """The chip-share cut keeps every published width; the keys it changes
+    are exactly those its file lists under REDUCED, with their published
+    values."""
+    import dataclasses
+    import importlib
+
+    mod = importlib.import_module("repro.configs.stablelm_1_6b")
+    full, chip = get_config("stablelm-1.6b"), get_chip_config("stablelm-1.6b")
+    changed = {f.name for f in dataclasses.fields(full)
+               if getattr(full, f.name) != getattr(chip, f.name)}
+    assert changed == set(mod.REDUCED)
+    for key, (published, here) in mod.REDUCED.items():
+        assert (getattr(full, key), getattr(chip, key)) == (published, here)
+    assert (chip.d_model, chip.num_heads, chip.d_ff, chip.vocab) == (
+        2048, 32, 5632, 100352)
+
+
+def test_chip_config_refuses_archs_without_a_cut():
+    with pytest.raises(ValueError, match="no chip-share cut"):
+        get_chip_config("deepseek-67b")
+    with pytest.raises(ValueError, match="unknown arch"):
+        get_chip_config("no-such-arch")

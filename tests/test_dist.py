@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.core.dist import CompressedAggregation
 
@@ -18,26 +18,15 @@ pytestmark = pytest.mark.skipif(
     jax.device_count() < 8, reason="needs 8 forced host devices"
 )
 
-# version compat: jax.shard_map/AxisType landed after the 0.4.x pin
-if hasattr(jax, "shard_map"):
-    from jax.sharding import AxisType
 
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
+def _shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    def _mesh():
-        return jax.make_mesh((4, 2), ("data", "model"),
-                             axis_types=(AxisType.Auto,) * 2)
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
 
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
-    def _mesh():
-        return jax.make_mesh((4, 2), ("data", "model"))
+def _mesh():
+    return jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 GRADS = {

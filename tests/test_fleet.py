@@ -416,7 +416,7 @@ def test_fleet_full_cohort_bit_matches_flat_wire(method, mesh_name, request):
     2-pod meshes, and charges exactly the static per-round uplink bits."""
     from repro.core.rules import WIRE_RULES
     from repro.data.pipeline import shared_slots_for_step
-    from repro.launch import compat, steps
+    from repro.launch import steps
 
     mesh = request.getfixturevalue(mesh_name)
     n, b, seq, total = 3, 1, 8, 4
@@ -426,7 +426,7 @@ def test_fleet_full_cohort_bit_matches_flat_wire(method, mesh_name, request):
     mode = "rr_shared" if method == "diana_rr" else "rr"
     key = jax.random.key(4)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # A: today's full-participation pipeline-fed loop
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg, m,
@@ -484,7 +484,7 @@ def test_fleet_resume_determinism(mesh_4x2, tmp_path):
     from repro.checkpoint import (
         load_meta, restore_fleet_checkpoint, save_fleet_checkpoint)
     from repro.core.rules import WIRE_RULES
-    from repro.launch import compat, steps
+    from repro.launch import steps
 
     mesh = mesh_4x2
     C, n, b, seq, total, cut = 10, 3, 1, 8, 6, 3
@@ -501,7 +501,7 @@ def test_fleet_resume_determinism(mesh_4x2, tmp_path):
     key = jax.random.key(4)
     path = str(tmp_path / "fleet.ckpt")
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg, m,
                                    mesh=mesh), shardings)
@@ -554,7 +554,7 @@ def test_fleet_partial_participation_trains_and_isolates_state(mesh_4x2):
     """C=12 > m=4 on the production wire: the run trains (finite losses),
     only sampled clients' store rows move, device shift tables stay
     O(cohort), and a wrong-cursor store is rejected at resume."""
-    from repro.launch import compat, steps
+    from repro.launch import steps
 
     mesh = mesh_4x2
     C, n, b, seq, total = 12, 3, 1, 8, 2  # 2 of 3 cohorts per fleet epoch
@@ -568,7 +568,7 @@ def test_fleet_partial_participation_trains_and_isolates_state(mesh_4x2):
                                     shard_size=5)
     cohorts = CohortSampler(C, m, seed=3)
     sampler = ReshuffleSampler(C, n, mode="rr", seed=1)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg, m,
                                    mesh=mesh), shardings)
@@ -612,7 +612,6 @@ def test_fleet_slotted_gates(mesh_4x2):
     cohort (straddling cohorts mix data positions), and non-shared
     sampler orders (DESIGN.md §3.9)."""
     from repro.core.rules import WIRE_RULES
-    from repro.launch import compat
 
     mesh = mesh_4x2
     n = 3
@@ -626,7 +625,7 @@ def test_fleet_slotted_gates(mesh_4x2):
         store=ClientStateStore.create(abstract.params, C,
                                       WIRE_RULES["diana_rr"], n_slots=n,
                                       dtype=np.float32), local_steps=ls)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with pytest.raises(ValueError, match="shared-slot"):
             mk(8, "with_replacement", "rr_shared")
         with pytest.raises(ValueError, match="divisible"):
@@ -963,7 +962,7 @@ def test_async_clean_run_bit_matches_sync_fleet(method, mesh_4x2):
     step — params, store shift tables, bits, cursors — for both the
     single-shift and the per-slot wire."""
     from repro.core.rules import WIRE_RULES
-    from repro.launch import compat, steps
+    from repro.launch import steps
 
     mesh = mesh_4x2
     n, b, seq, total = 3, 1, 8, 4
@@ -978,7 +977,7 @@ def test_async_clean_run_bit_matches_sync_fleet(method, mesh_4x2):
             abstract.params, m, WIRE_RULES[method], n_slots=agg.n_slots,
             dtype=np.float32, shard_size=3)
         cls = AsyncFleetRunner if async_mode else FleetRunner
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = jax.device_put(
                 steps.init_train_state(jax.random.key(0), cfg, agg, m,
                                        mesh=mesh), shardings)
@@ -1015,7 +1014,7 @@ def test_async_fleet_resume_under_chaos_bit_exact(mesh_4x2, tmp_path):
     from repro.checkpoint import (
         load_meta, restore_fleet_checkpoint, save_fleet_checkpoint)
     from repro.core.rules import WIRE_RULES
-    from repro.launch import compat, steps
+    from repro.launch import steps
 
     mesh = mesh_4x2
     C, n, b, seq, total, cut = 8, 3, 1, 8, 6, 3
@@ -1037,7 +1036,7 @@ def test_async_fleet_resume_under_chaos_bit_exact(mesh_4x2, tmp_path):
     trace = lambda mx: (b"skip" if mx.get("skipped")
                         else np.asarray(mx["loss"]).tobytes())
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg, m,
                                    mesh=mesh), shardings)
@@ -1112,7 +1111,7 @@ def test_fleet_mean_scale_tracks_population_mean(mesh_4x2):
     the population mean of the per-client store shifts — not the
     (C/M)-inflated cohort estimate the unscaled update would keep."""
     from repro.core.rules import WIRE_RULES
-    from repro.launch import compat, steps
+    from repro.launch import steps
 
     mesh = mesh_4x2
     C, n, b, seq, total = 8, 3, 1, 8, 4  # 2 whole fleet epochs
@@ -1121,7 +1120,7 @@ def test_fleet_mean_scale_tracks_population_mean(mesh_4x2):
     data = _population_tokens(cfg, C, n, b, seq)
     store = ClientStateStore.create(abstract.params, C, WIRE_RULES["diana"],
                                     dtype=np.float32, shard_size=3)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg, m,
                                    mesh=mesh), shardings)
@@ -1152,7 +1151,7 @@ def test_fleet_flat_nastya_pod_shift_roundtrip(mesh_4x2):
     config. Sampled clients' rows move, cursors advance by local_steps per
     participation, and device tables stay O(cohort)."""
     from repro.core.rules import WIRE_RULES
-    from repro.launch import compat, steps
+    from repro.launch import steps
 
     mesh = mesh_4x2
     C, n, b, seq, total, ls = 12, 4, 1, 8, 2, 2
@@ -1164,7 +1163,7 @@ def test_fleet_flat_nastya_pod_shift_roundtrip(mesh_4x2):
     store = ClientStateStore.create(abstract.params, C, WIRE_RULES["diana"],
                                     dtype=np.float32, shard_size=3)
     cohorts = CohortSampler(C, m, seed=3)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg, m,
                                    mesh=mesh, local_steps=ls), shardings)

@@ -7,7 +7,6 @@ at unit-test cost.
 """
 import jax
 
-from repro.launch import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -115,7 +114,7 @@ def test_train_step_runs_sharded(arch, method):
     # executable per process).
     jitted, abstract, shardings, _ = steps.make_train_step(
         cfg, mesh, agg=agg, lr=0.05, remat=False, seq_shard=False)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = steps.init_train_state(jax.random.key(0), cfg, agg,
                                        num_clients(mesh))
         state = jax.device_put(state, shardings)
@@ -142,7 +141,7 @@ def test_train_step_loss_decreases():
                                 shift_dtype=jnp.float32)
     jitted, abstract, shardings, _ = steps.make_train_step(
         cfg, mesh, agg=agg, lr=0.2, remat=False)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg,
                                    num_clients(mesh)), shardings)
@@ -163,7 +162,7 @@ def test_serve_step_sharded(arch):
     cache = T.init_cache(params, cfg, batch=B, cache_len=S)
     serve, lower_args = steps.make_serve_step(cfg, mesh)
     tokens = jnp.zeros((B, 1), jnp.int32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, (psh, csh, tsh) = lower_args(
             jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params),
             jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), cache),
@@ -194,3 +193,53 @@ def test_train_docstring_example_flags_stay_valid():
     args = parser.parse_args(example.split())
     assert args.fraction == parser.get_default("fraction") == 0.02
     assert "--fraction 0.02" in train.__doc__
+
+
+def test_attached_mesh_puts_one_client_on_each_device():
+    from repro.launch.mesh import make_attached_mesh
+
+    n = jax.device_count()
+    mesh = make_attached_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert (mesh.shape["data"], mesh.shape["model"]) == (n, 1)
+    assert num_clients(mesh) == n
+    pods = make_attached_mesh(2)
+    assert pods.axis_names == ("pod", "data", "model")
+    assert tuple(pods.shape.values()) == (2, n // 2, 1)
+    with pytest.raises(ValueError, match="do not split"):
+        make_attached_mesh(3)
+
+
+def test_build_runs_reduced_config_off_the_chip():
+    """Off a TPU, train.build runs the reduced config without remat on the
+    attached devices (the chip-share cut is for the chip only)."""
+    from repro.launch import train
+
+    ap = train.build_parser()
+    tr = train.build(ap, ap.parse_args(["--seq", "16", "--clients", "16"]))
+    assert tr.cfg == reduced(get_config("stablelm-1.6b"), seq=16)
+    assert tr.remat is False
+    assert tr.m == jax.device_count()
+    assert tr.agg.mean_scale == tr.m / 16
+
+
+def test_compile_cache_dir(monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR wins and is left to JAX; otherwise the
+    cache goes to the fixed .jax_cache/ at the checkout root."""
+    import pathlib
+
+    from repro.launch import cache
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert cache.CHECKOUT_CACHE == root / ".jax_cache"
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert cache.enable_compile_cache() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            root / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

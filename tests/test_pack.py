@@ -62,6 +62,41 @@ def test_unpack_matches_ref(rows, d, nibble):
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("nibble", [False, True])
+@pytest.mark.parametrize("rows", [72, 200])
+def test_row_blocked_grid_matches_ref(monkeypatch, rows, nibble):
+    """With a small block budget every kernel walks several row blocks (and
+    unpack_reduce a (row block, rank) grid), padding the last one: bytes,
+    decode and rank-order mean still match the oracles bitwise."""
+    from repro.kernels import pack
+
+    monkeypatch.setattr(pack, "_BLOCK_BYTES", 32 * 4 * 128)
+    jax.clear_caches()
+    levels, d, ranks = (7 if nibble else 127), 128, 4
+    assert pack._row_block(rows + (-rows) % BLOCK_ROWS, d, nibble) < rows
+    slabs = [_slab(rows, d, seed=97 + r) for r in range(ranks)]
+    packed, scales = zip(*(pack_slab(v, u, levels=levels, nibble=nibble)
+                           for v, u in slabs))
+    for (v, u), p, s in zip(slabs, packed, scales):
+        pr, sr = ref.pack_slab_ref(v, u, levels=levels, nibble=nibble,
+                                   block_rows=BLOCK_ROWS)
+        assert np.array_equal(np.asarray(p), np.asarray(pr))
+        np.testing.assert_allclose(np.asarray(s), np.asarray(sr),
+                                   rtol=1e-6, atol=0)
+        assert np.array_equal(
+            np.asarray(unpack_slab(p, s, levels=levels, n_rows=rows,
+                                   nibble=nibble)),
+            np.asarray(ref.unpack_slab_ref(p, s, levels=levels, n_rows=rows,
+                                           nibble=nibble)))
+    gp, gs = jnp.stack(packed), jnp.stack(scales)
+    assert np.array_equal(
+        np.asarray(unpack_reduce(gp, gs, levels=levels, n_rows=rows,
+                                 nibble=nibble)),
+        np.asarray(ref.unpack_reduce_ref(gp, gs, levels=levels, n_rows=rows,
+                                         nibble=nibble)))
+    jax.clear_caches()
+
+
 # ---------------------------------------------------------------------------
 # round-trip properties of the lattice
 # ---------------------------------------------------------------------------

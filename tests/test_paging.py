@@ -348,7 +348,7 @@ def test_paged_fleet_bit_matches_in_ram(method, mesh_4x2, tmp_path):
     shift tables, bits, cursors — to the in-RAM run, for diana AND
     diana_rr, and the checkpoint manifest records the data-store spec."""
     from repro.core.rules import WIRE_RULES
-    from repro.launch import compat, steps
+    from repro.launch import steps
 
     mesh = mesh_4x2
     # diana_rr's shared-slot wire needs C % m == 0 (no straddling cohorts);
@@ -366,7 +366,7 @@ def test_paged_fleet_bit_matches_in_ram(method, mesh_4x2, tmp_path):
         store = ClientStateStore.create(
             abstract.params, C, WIRE_RULES[method], n_slots=agg.n_slots,
             dtype=np.float32, shard_size=3)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = jax.device_put(
                 steps.init_train_state(jax.random.key(0), _tiny_cfg(), agg,
                                        m, mesh=mesh), shardings)
@@ -404,7 +404,7 @@ def test_paged_fleet_resume_and_layout_refusal(mesh_4x2, tmp_path):
                                   restore_fleet_checkpoint,
                                   save_fleet_checkpoint)
     from repro.core.rules import WIRE_RULES
-    from repro.launch import compat, steps
+    from repro.launch import steps
     from test_fleet import _tiny_cfg
 
     mesh = mesh_4x2
@@ -423,7 +423,7 @@ def test_paged_fleet_resume_and_layout_refusal(mesh_4x2, tmp_path):
         cohorts=CohortSampler(C, m, seed=9), store=store,
         start_round=start, paged=pager)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), _tiny_cfg(), agg, m,
                                    mesh=mesh), shardings)
@@ -485,7 +485,7 @@ def test_paged_async_fleet_under_dropout_bit_matches_ram(mesh_4x2, tmp_path):
     non-completers' page cursors hold still, and the injection schedule is
     unchanged by paging."""
     from repro.core.rules import WIRE_RULES
-    from repro.launch import compat, steps
+    from repro.launch import steps
     from test_fleet import _tiny_cfg
 
     from test_fleet import _fleet_setup, _population_tokens
@@ -504,7 +504,7 @@ def test_paged_async_fleet_under_dropout_bit_matches_ram(mesh_4x2, tmp_path):
         store = ClientStateStore.create(
             abstract.params, C, WIRE_RULES["diana"], dtype=np.float32,
             shard_size=3)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = jax.device_put(
                 steps.init_train_state(jax.random.key(0), _tiny_cfg(), agg,
                                        m, mesh=mesh), shardings)

@@ -220,7 +220,6 @@ def _run_resume_cycle(mesh, *, local_steps, eta, n_batches, tmp_path):
     """6 pipeline-fed steps with a checkpoint (state + cursor) snapped after
     step 3, then restore + rerun 4..6: trajectories must bit-match."""
     from repro.checkpoint import load_meta, restore_train_state, save_pytree
-    from repro.launch import compat
 
     seq, b, total, cut = 8, 1, 6, 3
     cfg, m, jitted, abstract, shardings, batch_sh, state = _setup_step(
@@ -230,7 +229,7 @@ def _run_resume_cycle(mesh, *, local_steps, eta, n_batches, tmp_path):
     key = jax.random.key(4)
     path = str(tmp_path / "mid.ckpt")
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(state, shardings)
         stream = make_batch_stream(
             data, ReshuffleSampler(m, n_batches, seed=1),
@@ -292,7 +291,6 @@ def test_one_pod_pipeline_run_bit_matches_flat(mesh_4x2, mesh_1x4x2):
     1-pod two-level step and the flat step produces bitwise-identical
     parameter trajectories (tests/test_pod_wire.py proves it for the wire;
     this proves it end-to-end through the pipeline-fed step)."""
-    from repro.launch import compat
 
     seq, b, n, total = 8, 1, 4, 3
     results = {}
@@ -300,7 +298,7 @@ def test_one_pod_pipeline_run_bit_matches_flat(mesh_4x2, mesh_1x4x2):
         cfg, m, jitted, _, shardings, batch_sh, state = _setup_step(
             mesh, seq=seq)
         data = _token_data(cfg, m, n, b, seq)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = jax.device_put(state, shardings)
             stream = make_batch_stream(
                 data, ReshuffleSampler(m, n, seed=1),

@@ -19,7 +19,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.dist import CompressedAggregation, DianaState
 from repro.data.logreg import make_federated_logreg
-from repro.launch import compat
 
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 8, reason="needs 8 forced host devices"
@@ -27,9 +26,9 @@ pytestmark = pytest.mark.skipif(
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    return compat.shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs,
-                            axis_names=set(mesh.axis_names), check_vma=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=set(mesh.axis_names), check_vma=False)
 
 
 GRADS = {
@@ -359,16 +358,20 @@ def test_per_slot_shifts_reach_fixed_point(mesh_2x2x2):
     np.testing.assert_allclose(np.asarray(got["w"]), mean, atol=1e-5)
 
 
-def test_ef_wire_fixed_point_on_logreg(mesh_4x2):
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_ef_wire_fixed_point_on_logreg(mesh_4x2, seed):
     """Error feedback on the wire: the residual memory telescopes, so the
     RUNNING MEAN of the directions converges to the exact gradient mean at
     rate ||e_T||/T — while the memory-free 'q' wire's mean keeps the
     compression noise floor. (The EF remedy the paper cites, now production.)
+    ||e_T|| is bounded but varies with the key stream, so T is chosen for
+    the bound to hold on every seed, not for one stream's lucky residual.
     """
     grads, mean = _logreg_grads()
     agg = CompressedAggregation(method="ef", wire="shared", fraction=0.25,
                                 shift_dtype=jnp.float32)
-    got = _run_rounds(agg, mesh_4x2, 300, grads=grads, reduce="mean")
+    got = _run_rounds(agg, mesh_4x2, 1200, grads=grads, reduce="mean",
+                      seed=seed)
     scale = float(np.abs(mean).max())
     err_ef = float(np.abs(np.asarray(got["w"]) - mean).max())
     assert err_ef < 0.02 * scale + 1e-4, (err_ef, scale)
@@ -420,7 +423,7 @@ def test_per_slot_wire_matches_simulator_and_pipeline_order(mesh_4x2):
         cfg, mesh, agg=agg, lr=gamma, remat=False, seq_shard=False)
     stream = make_batch_stream(
         {"tokens": tokens.astype(np.int32)}, sampler, prefetch=False)
-    with compat.set_mesh(mesh), stream:
+    with jax.set_mesh(mesh), stream:
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg, m, lr=gamma,
                                    mesh=mesh), shardings)
@@ -539,7 +542,7 @@ def test_pod_nastya_matches_simulator(name, mesh_4x2):
     jitted, abstract, shardings, batch_sh = steps.make_train_step(
         cfg, mesh, agg=agg, lr=gamma, eta=eta, local_steps=local_steps,
         remat=False, seq_shard=False)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg, m, lr=gamma,
                                    mesh=mesh, local_steps=local_steps),
@@ -582,7 +585,7 @@ def test_nastya_two_pod_step_trains(mesh_2x2x2):
     jitted, abstract, shardings, _ = steps.make_train_step(
         cfg, mesh, agg=agg, lr=0.05, eta=0.2, local_steps=local_steps,
         remat=False, seq_shard=False)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg, m, mesh=mesh,
                                    local_steps=local_steps), shardings)
@@ -615,7 +618,7 @@ def test_nastya_two_pod_diana_rr_trains(mesh_2x2x2):
     jitted, abstract, shardings, _ = steps.make_train_step(
         cfg, mesh, agg=agg, lr=0.05, eta=0.2, local_steps=local_steps,
         remat=False, seq_shard=False)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(
             steps.init_train_state(jax.random.key(0), cfg, agg, m, mesh=mesh,
                                    local_steps=local_steps), shardings)

@@ -233,7 +233,7 @@ def _run_fleet(mesh, method, setup, data, *, total, sink=None):
     """One C = 2m cohort-RR fleet walk; returns (final state, store,
     callback metrics) — with `sink` installed for the duration."""
     from repro.core.rules import WIRE_RULES
-    from repro.launch import compat, steps
+    from repro.launch import steps
 
     cfg, m, agg, jitted, abstract, shardings, batch_sh = setup
     C = 2 * m
@@ -242,7 +242,7 @@ def _run_fleet(mesh, method, setup, data, *, total, sink=None):
     if sink is not None:
         telemetry.install(sink)
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = jax.device_put(
                 steps.init_train_state(jax.random.key(0), cfg, agg, m,
                                        mesh=mesh), shardings)
@@ -334,7 +334,7 @@ def test_async_chaos_counters_match_planner_replay(mesh_4x2):
     mass (1 per on-time reporter + the staleness discounts), not the
     vacuous post-rescale sum (always m)."""
     from repro.core.rules import WIRE_RULES
-    from repro.launch import compat, steps
+    from repro.launch import steps
 
     mesh = mesh_4x2
     method, total = "diana", 6
@@ -350,7 +350,7 @@ def test_async_chaos_counters_match_planner_replay(mesh_4x2):
     telemetry.install(sink)
     seen = []
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = jax.device_put(
                 steps.init_train_state(jax.random.key(0), cfg, agg, m,
                                        mesh=mesh), shardings)
@@ -418,7 +418,7 @@ def test_debug_metrics_opt_in(mesh_4x2):
     metrics pytree without perturbing the trajectory: params after two
     steps are bitwise identical to the default step's."""
     from repro.core.dist import CompressedAggregation
-    from repro.launch import compat, steps
+    from repro.launch import steps
     from repro.launch.mesh import num_clients
 
     mesh = mesh_4x2
@@ -432,7 +432,7 @@ def test_debug_metrics_opt_in(mesh_4x2):
             cfg, mesh, agg=agg, lr=0.05, remat=False, seq_shard=False,
             debug_metrics=debug)
         data = _population_tokens(cfg, m, 3, 1, 8)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = jax.device_put(
                 steps.init_train_state(jax.random.key(0), cfg, agg, m,
                                        mesh=mesh), shardings)
