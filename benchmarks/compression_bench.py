@@ -830,9 +830,9 @@ def run_telemetry_bench(*, quick: bool, reps: int):
         def run_rounds():
             t0 = time.perf_counter()
             for r in range(rounds):
-                y = step(x)
-                with telemetry.span("device_step", round=r):
-                    y.block_until_ready()
+                with telemetry.span("step_dispatch", round=r):
+                    y = step(x)
+                y.block_until_ready()
                 telemetry.counter("fleet.uplink_bits", 8.0 * d * d, round=r)
                 telemetry.round_metrics(
                     r, {"loss": y[0, 0], "grad_norm": y[1, 1]})

@@ -200,13 +200,20 @@ class _PrefetchStream:
                 "batches; rebuild the stream from the last checkpointed "
                 "cursor")
         try:
+            # `input_wait`: the calling thread blocked on the batch it is
+            # about to hand to the step (the worker's build, or the whole
+            # build when there is no prefetch)
             if self._pool is None:
                 plan, _ = self._submit()
-                return self._emit(plan, self._build_traced(plan))
+                with telemetry.span("input_wait"):
+                    built = self._build_traced(plan)
+                return self._emit(plan, built)
             if self._pending is None:
                 self._pending = self._submit()
             (plan, fut), self._pending = self._pending, self._submit()
-            return self._emit(plan, fut.result())
+            with telemetry.span("input_wait"):
+                built = fut.result()
+            return self._emit(plan, built)
         except BaseException:
             self.close()
             raise

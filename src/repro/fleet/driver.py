@@ -192,7 +192,12 @@ class FleetRunner:
             callback: Callable[[int, Any, dict], None] | None = None):
         """Advance `rounds` fleet rounds from `state`; returns the final
         TrainState. `callback(round, state, metrics)` fires per round
-        (logging/checkpoint hooks). The store is updated in place."""
+        (logging/checkpoint hooks). The store is updated in place.
+
+        Telemetry spans per round: `gather` (store rows out),
+        `step_dispatch` (the step's enqueue only, not its device time),
+        `shift_fetch` (the wait for the device plus the device->host copy
+        of the cohort's rows) and `scatter` (the host store write)."""
         store = self._store
         # paged runs route gather/scatter through the pager (one I/O
         # object for data pages and state rows); it delegates to the store
@@ -211,16 +216,17 @@ class FleetRunner:
                         "bug: the constructor gates should have rejected "
                         "the config)")
                 slots = jnp.asarray(fr.cols[0], jnp.int32)
-                with telemetry.span("device_step", round=fr.round):
+                with telemetry.span("step_dispatch", round=fr.round):
                     state, metrics = self._jitted(state, fr.batch, key,
                                                   slots)
             else:
-                with telemetry.span("device_step", round=fr.round):
+                with telemetry.span("step_dispatch", round=fr.round):
                     state, metrics = self._jitted(state, fr.batch, key)
             if store.has_shifts:
+                with telemetry.span("shift_fetch", round=fr.round):
+                    rows = jax.device_get(self._device_shifts(state))
                 with telemetry.span("scatter", round=fr.round):
-                    io.scatter(fr.cohort,
-                               jax.device_get(self._device_shifts(state)))
+                    io.scatter(fr.cohort, rows)
             store.advance(fr.cohort, self._local_steps)
             store.add_bits(fr.cohort, self._bits_per_client)
             # one participation schema across sync/async: the sync round is
@@ -364,7 +370,7 @@ class AsyncFleetRunner(FleetRunner):
         gains per-round participation stats (`on_time`, `completed`,
         `weight_sum`, `dropped`, `deadline` — the same schema the sync
         runner emits); zero-completer rounds report `{"skipped": True}`
-        and leave the state untouched."""
+        and leave the state untouched. Spans as `FleetRunner.run`'s."""
         store = self._store
         io = self._pager if self._pager is not None else store
         for _ in range(rounds):
@@ -394,7 +400,7 @@ class AsyncFleetRunner(FleetRunner):
             state = _steps.with_cohort_shifts(
                 state, gathered, self._shardings, self._shift_field)
             weights = jnp.asarray(plan.weights)
-            with telemetry.span("device_step", round=fr.round):
+            with telemetry.span("step_dispatch", round=fr.round):
                 if self._slotted:
                     slots = jnp.asarray(fr.cols[0], jnp.int32)
                     state, metrics = self._jitted(state, fr.batch, key,
@@ -406,8 +412,9 @@ class AsyncFleetRunner(FleetRunner):
                 # only completers persist their round: non-completing rows
                 # of the device table are discarded (the next gather
                 # overwrites them), leaving their store rows pre-round
-                with telemetry.span("scatter", round=fr.round):
+                with telemetry.span("shift_fetch", round=fr.round):
                     upd = jax.device_get(self._device_shifts(state))
+                with telemetry.span("scatter", round=fr.round):
                     idx = np.flatnonzero(comp)
                     self._io_retry(
                         io.scatter, fr.cohort[idx],

@@ -20,6 +20,12 @@
   - the server update is plain SGD (Algorithms 2-5; momentum/AdamW are the
     beyond-paper variants, state replicated over clients, TP over model).
 
+The train step names its phases with `jax.named_scope`: `client_grads`
+(forward and backward, with the recomputed forward under remat), `wire`
+(every exchange: compression, collectives, kernels, shift updates) and
+`server_update`. The scopes are metadata only; they reach the compiled
+program's `op_name`s, so a profiler trace can be split by phase.
+
 `make_prefill_step` / `make_serve_step` are pure-GSPMD inference paths (no
 client wire — serving has no gradients to compress).
 
@@ -352,9 +358,10 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
 
     def grads_and_loss(params_stacked, batch_c):
         """Per-client (loss, grad) under GSPMD: vmap over the client dim."""
-        return jax.vmap(
-            lambda p, b: jax.value_and_grad(loss_fn)(p, b)
-        )(params_stacked, batch_c)
+        with jax.named_scope("client_grads"):
+            return jax.vmap(
+                lambda p, b: jax.value_and_grad(loss_fn)(p, b)
+            )(params_stacked, batch_c)
 
     def broadcast_clients(tree):
         """params -> (M, *shape) client-stacked view (replication, no copy
@@ -516,8 +523,9 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
             losses, g = grads_and_loss(x_clients, batch_j)
             kd = jax.random.key_data(
                 jax.random.fold_in(rkey, salts.NASTYA_LOCAL_SALT + t))
-            direction, shifts, mean_shift = local_wire(
-                g, shifts, mean_shift, kd, slot_j)
+            with jax.named_scope("wire"):
+                direction, shifts, mean_shift = local_wire(
+                    g, shifts, mean_shift, kd, slot_j)
             x = jax.tree.map(
                 lambda xi, d: (xi.astype(jnp.float32)
                                - gamma * d.astype(jnp.float32)
@@ -533,9 +541,10 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
             lambda p, xn: (p[None].astype(jnp.float32)
                            - xn.astype(jnp.float32))
             / (gamma * local_steps), state.params, x_pods)
-        direction, new_psh, new_pms = pod_wire(
-            g_pod, state.pod_shifts, state.pod_mean_shift,
-            jax.random.key_data(rkey))
+        with jax.named_scope("wire"):
+            direction, new_psh, new_pms = pod_wire(
+                g_pod, state.pod_shifts, state.pod_mean_shift,
+                jax.random.key_data(rkey))
         gnorm = jnp.sqrt(sum(
             jnp.sum(jnp.square(x.astype(jnp.float32)))
             for x in jax.tree.leaves(g_pod)) / n_pods_)
@@ -550,10 +559,11 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
         batch_c = jax.tree.map(
             lambda x: x.reshape((m, bsz) + x.shape[1:]), batch)
         losses, g = grads_and_loss(broadcast_clients(state.params), batch_c)
-        direction, new_shifts, new_ms, new_psh, new_pms = full_wire(
-            g, state.shifts, state.mean_shift, state.pod_shifts,
-            state.pod_mean_shift, jax.random.key_data(rkey), slots[0],
-            weights)
+        with jax.named_scope("wire"):
+            direction, new_shifts, new_ms, new_psh, new_pms = full_wire(
+                g, state.shifts, state.mean_shift, state.pod_shifts,
+                state.pod_mean_shift, jax.random.key_data(rkey), slots[0],
+                weights)
         gnorm = jnp.sqrt(sum(
             jnp.sum(jnp.square(x.astype(jnp.float32)))
             for x in jax.tree.leaves(g)) / m)
@@ -606,10 +616,11 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
             (direction, new_shifts, new_ms, new_psh, new_pms, loss,
              gnorm, extras) = flat_round(state, batch, rkey, slots,
                                          weights if elastic else None)
-        updates, new_opt = opt.update(
-            jax.tree.map(lambda d: d.astype(jnp.float32), direction),
-            state.opt_state, state.params)
-        new_params = optim.apply_updates(state.params, updates)
+        with jax.named_scope("server_update"):
+            updates, new_opt = opt.update(
+                jax.tree.map(lambda d: d.astype(jnp.float32), direction),
+                state.opt_state, state.params)
+            new_params = optim.apply_updates(state.params, updates)
         metrics = {"loss": loss, "grad_norm": gnorm, **extras}
         return TrainState(new_params, new_shifts, new_ms, state.step + 1,
                           new_opt, new_psh, new_pms), metrics

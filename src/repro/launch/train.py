@@ -581,10 +581,10 @@ def run(args, tr: Trainer, callback=None) -> steps.TrainState:
                     # stateless sampler, so --resume re-derives it exactly
                     slots = jnp.asarray(shared_slots_for_step(
                         sampler, t, args.local_steps, n_slots=agg.n_slots))
-                    with telemetry.span("device_step", round=t):
+                    with telemetry.span("step_dispatch", round=t):
                         state, metrics = jitted(state, batch, key, slots)
                 else:
-                    with telemetry.span("device_step", round=t):
+                    with telemetry.span("step_dispatch", round=t):
                         state, metrics = jitted(state, batch, key)
                 if telemetry.enabled():
                     telemetry.counter("wire.uplink_bits",

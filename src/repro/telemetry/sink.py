@@ -14,7 +14,11 @@ thread converts them (`_jsonable` -> `np.asarray`), so the one
 device->host fetch the loop already pays happens off the dispatch path.
 Spans read `time.perf_counter()` twice and never call `block_until_ready`,
 so a span measures host phase time (dispatch, not device completion) by
-construction.
+construction. Each span also enters a `jax.profiler.TraceAnnotation` of its
+bare name, so under `jax.profiler.trace` it lands in the profiler's host
+plane on the device trace's clock; an idle gap on the device can then be
+put down to the host phase that spans it. `jax.profiler` is imported when
+the first span is entered, never by `import repro.telemetry`.
 
 Thread model: builds/spans fire from both the round loop and the prefetch
 worker, so emission is queue-based (`queue.SimpleQueue`, lock-free put)
@@ -50,9 +54,10 @@ def _jsonable(v):
 
 
 class _Span:
-    """One host phase interval; records (ts, dur, tid, depth) on exit."""
+    """One host phase interval; records (ts, dur, tid, depth) on exit and
+    mirrors it as a profiler host annotation."""
 
-    __slots__ = ("_sink", "_name", "_args", "_t0", "_depth")
+    __slots__ = ("_sink", "_name", "_args", "_t0", "_depth", "_trace")
 
     def __init__(self, sink: "MetricsSink", name: str, args: dict):
         self._sink = sink
@@ -63,11 +68,19 @@ class _Span:
         tls = self._sink._tls
         self._depth = getattr(tls, "depth", 0)
         tls.depth = self._depth + 1
+        # imported here, not with the module: repro.telemetry stays
+        # numpy-only. The bare name: args would be encoded into the
+        # profiler event's name (`name#k=v#`), so they stay in the record
+        from jax.profiler import TraceAnnotation
+
+        self._trace = TraceAnnotation(self._name)
+        self._trace.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._trace.__exit__(None, None, None)
         sink = self._sink
         sink._tls.depth = self._depth
         rec = {"v": SCHEMA_VERSION, "kind": "span",

@@ -110,6 +110,63 @@ def test_module_helpers_are_noops_when_off():
     assert telemetry.active() is None
 
 
+def test_span_without_a_sink_is_the_shared_noop():
+    from repro.telemetry import sink as sink_mod
+
+    assert telemetry.active() is None
+    assert telemetry.span("input_wait") is sink_mod._NOOP
+    assert telemetry.span("gather", round=3) is sink_mod._NOOP
+
+
+def test_import_is_numpy_only():
+    """`import repro.telemetry` and installing a sink pull in no jax; the
+    first span entered imports the profiler for its host annotation."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from repro import telemetry\n"
+        "sink = telemetry.install(telemetry.MetricsSink())\n"
+        "assert 'jax' not in sys.modules, 'jax imported before a span'\n"
+        "with telemetry.span('x'):\n"
+        "    pass\n"
+        "assert 'jax.profiler' in sys.modules\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+def test_spans_land_in_the_profiler_host_plane(tmp_path):
+    """A span of an installed sink is a profiler host event of the same
+    bare name (its args stay in the record): the batch stream's
+    `input_wait` on the caller's thread and `assemble` on the worker's."""
+    from jax.profiler import ProfileData
+
+    data = {"x": np.arange(2 * 4 * 3, dtype=np.float32).reshape(2, 4, 3)}
+    sink = telemetry.install(telemetry.MetricsSink())
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            with telemetry.span("phase", round=7):
+                with make_batch_stream(data, ReshuffleSampler(2, 4, seed=0),
+                                       prefetch=True) as stream:
+                    for _ in range(3):
+                        next(stream)
+    finally:
+        telemetry.uninstall()
+        sink.close()
+    (xplane,) = tmp_path.rglob("*.xplane.pb")
+    host = {}
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        if plane.name.startswith("/host"):
+            for i, line in enumerate(plane.lines):  # a line per thread
+                for e in line.events:
+                    host.setdefault(e.name, set()).add((plane.name, i))
+    assert {"phase", "input_wait", "assemble"} <= set(host)
+    assert host["input_wait"] == host["phase"]
+    assert host["input_wait"].isdisjoint(host["assemble"])
+    recorded = [e for e in sink.events() if e["name"] == "phase"]
+    assert recorded[0]["args"] == {"round": 7}
+
+
 def test_session_installs_and_always_uninstalls():
     sink = telemetry.MetricsSink()
     with pytest.raises(RuntimeError, match="boom"):
@@ -302,10 +359,15 @@ def test_telemetry_on_bit_matches_off(method, mesh_4x2):
     assert telemetry.validate_events(events) == []
     spans = [e for e in events if e["kind"] == "span"]
     names = {e["name"] for e in spans}
-    assert {"gather", "device_step", "scatter", "assemble"} <= names
+    assert {"gather", "step_dispatch", "shift_fetch", "scatter",
+            "assemble"} <= names
+    # the device wait and the host store write are separate spans, one of
+    # each per round
+    for name in ("shift_fetch", "scatter"):
+        assert sum(e["name"] == name for e in spans) == total
     # prefetch assembly runs on the worker thread, phases on the caller's
     tids = {e["name"]: e["tid"] for e in spans}
-    assert tids["assemble"] != tids["device_step"]
+    assert tids["assemble"] != tids["step_dispatch"]
     rms = [e for e in events if e["kind"] == "round_metrics"]
     assert [e["round"] for e in rms] == list(range(total))
     # one static run_meta with the wire accounting
