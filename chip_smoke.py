@@ -172,7 +172,8 @@ def leaf_row_shapes(cfg):
 
 def kernel_parity(jax, cfg, fraction: float, ranks: int):
     """Each wire kernel against its oracle, on the chip, at every leaf
-    shape of `cfg`: Rand-block gather and scatter, the fused DIANA update,
+    shape of `cfg`: Rand-block gather, scatter and in-place window
+    write-back, the fused DIANA update,
     and the packed wire's pack / unpack / unpack-reduce (levels 127, as the
     packed8 run uses, with `ranks` gathered slabs)."""
     import jax.numpy as jnp
@@ -181,7 +182,11 @@ def kernel_parity(jax, cfg, fraction: float, ranks: int):
     from repro.kernels import ref
     from repro.kernels.diana_shift import diana_shift_update
     from repro.kernels.pack import pack_slab, unpack_reduce, unpack_slab
-    from repro.kernels.randk import randk_compress, randk_decompress
+    from repro.kernels.randk import (
+        randk_compress,
+        randk_decompress,
+        randk_decompress_into,
+    )
 
     levels = 127
     f32 = jnp.float32
@@ -215,6 +220,13 @@ def kernel_parity(jax, cfg, fraction: float, ranks: int):
         check("randk_decompress", (n, d), dense, ref.randk_decompress_ref(
             vals, start, n_rows=n, block_rows=BLOCK_ROWS), 0.0)
         del dense
+        written = randk_decompress_into(rows, vals, start, jnp.int32(0),
+                                        n_rows=n, interpret=False)
+        check("randk_decompress_into", (n, d), written,
+              ref.randk_decompress_into_ref(rows, vals, start, jnp.int32(0),
+                                            n_rows=n,
+                                            block_rows=BLOCK_ROWS), 0.0)
+        del written
         flat = [jax.random.normal(k, (n * d + (-(n * d)) % 128,), f32)
                 for k in keys[2:6]]
         got = diana_shift_update(*flat, alpha=0.5, beta=0.125,

@@ -19,8 +19,11 @@ Consumers:
 
 - `repro.core.algorithms` routes per-client compression and the shift-rule
   updates (repro.core.rules) through `compress_clients` / `tree_diana_shift`;
-- `repro.core.dist` routes the shared wire through `wire_compress` /
-  `wire_decompress`;
+- `repro.core.dist` routes the shared wire through `wire_exchange` /
+  `exchange_slab` and the Rand-block kernels: `wire_compress` /
+  `wire_decompress` on the dense-leaf paths ('q', 'ef'),
+  `wire_decompress_into` (the in-place window write-back) on the
+  window-sparse DIANA path;
 - `benchmarks/compression_bench.py` times both backends against the seed
   per-leaf `jax.random.choice` path and writes BENCH_compression.json.
 
@@ -54,6 +57,7 @@ from repro.kernels.randk import (
     BLOCK_ROWS,
     randk_compress,
     randk_decompress,
+    randk_decompress_into,
     randk_mask,
 )
 from repro.kernels.ops import diana_shift as _pallas_diana_shift
@@ -232,14 +236,31 @@ class CompressionBackend:
                       axes: tuple[str, ...], weight: jax.Array | None = None,
                       wire_dtype: str = "f32", levels: int | None = None,
                       quant_u: jax.Array | None = None):
-        """One level of the (possibly hierarchical) shared wire: circular
-        gather of the k-row slab, then the sparse collective over `axes`.
+        """One level of the (possibly hierarchical) shared wire on a dense
+        leaf: circular gather of the k-row slab (`wire_compress`), then the
+        sparse collective over `axes` (`exchange_slab`).
 
-        Returns (own_vals, mean_vals). This is the per-level dispatch point:
-        the intra-pod ("data") and inter-pod ("pod") exchanges both land
-        here, each with its own start_block/k_blocks, so only the compressed
-        slab ever crosses either wire. Must run inside a shard_map whose
-        manual axes include `axes`.
+        Returns (own_vals, mean_vals). The dense-leaf paths of the shared
+        wire ('q', 'ef') land here; the window-sparse DIANA path of
+        `core.dist` gathers its own slab and calls `exchange_slab` directly.
+        """
+        vals = self.wire_compress(rows, start_block, k_blocks=k_blocks,
+                                  block_rows=block_rows)
+        return self.exchange_slab(vals, axes=axes, weight=weight,
+                                  wire_dtype=wire_dtype, levels=levels,
+                                  quant_u=quant_u)
+
+    def exchange_slab(self, vals: jax.Array, *, axes: tuple[str, ...],
+                      weight: jax.Array | None = None,
+                      wire_dtype: str = "f32", levels: int | None = None,
+                      quant_u: jax.Array | None = None):
+        """The sparse collective of one wire level on an already gathered
+        (K, D) f32 slab. Returns (own_vals, mean_vals).
+
+        This is the per-level dispatch point: the intra-pod ("data") and
+        inter-pod ("pod") exchanges both land here, each with its own
+        window, so only the compressed slab ever crosses either wire. Must
+        run inside a shard_map whose manual axes include `axes`.
 
         `weight` (per-rank scalar, pre-normalized so an all-ones cohort gives
         exactly 1.0) scales this rank's contribution to the collective mean —
@@ -267,8 +288,6 @@ class CompressionBackend:
         drawn by the caller from the level key + WIRE_QUANT_SALT; required
         iff `levels` is set.
         """
-        vals = self.wire_compress(rows, start_block, k_blocks=k_blocks,
-                                  block_rows=block_rows)
         if wire_dtype in ("packed8", "packed4"):
             nib = wire_dtype == "packed4"
             packed, scales = self.pack_slab(vals, quant_u, levels=levels,
@@ -342,6 +361,20 @@ class CompressionBackend:
                                     interpret=self.interpret)
         return ref.randk_decompress_ref(vals, start_block, n_rows=n_rows,
                                         block_rows=block_rows)
+
+    def wire_decompress_into(self, into: jax.Array, vals: jax.Array,
+                             start_block: jax.Array, base_block: jax.Array, *,
+                             n_rows: int, block_rows: int) -> jax.Array:
+        """(K, D) vals written over the circular window of the n_rows-row
+        segment at block `base_block` of `into` (R, D); the rest is kept.
+        On the pallas backend the output aliases `into` (in place)."""
+        if self.is_pallas:
+            return randk_decompress_into(into, vals, start_block, base_block,
+                                         n_rows=n_rows, block_rows=block_rows,
+                                         interpret=self.interpret)
+        return ref.randk_decompress_into_ref(into, vals, start_block,
+                                             base_block, n_rows=n_rows,
+                                             block_rows=block_rows)
 
 
 def get_backend(name: str | CompressionBackend | None = None) -> CompressionBackend:

@@ -74,6 +74,7 @@ the parameter pytree, and `lax.pmean` over the level's axes is the server.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, NamedTuple
 
 import jax
@@ -283,10 +284,12 @@ class CompressedAggregation:
     # with omega = n_rows/k_rows - 1 = 1/fraction - 1 (block-granular Rand-k).
 
     @staticmethod
-    def _row_view(leaf):
-        if leaf.ndim >= 2:
-            return jnp.reshape(leaf, (-1, leaf.shape[-1]))
-        return jnp.reshape(leaf, (-1, 1))
+    def _row_view(leaf, lead: int = 0):
+        """(rows, cols) view of a leaf; `lead` leading axes (a shift table's
+        slot axis) stay in front: (S, rows, cols)."""
+        shape = leaf.shape[lead:]
+        cols = shape[-1] if len(shape) >= 2 else 1
+        return jnp.reshape(leaf, leaf.shape[:lead] + (-1, cols))
 
     def _k(self, size: int, fraction: float) -> int:
         return max(1, int(fraction * size))
@@ -430,15 +433,29 @@ class CompressedAggregation:
         shift trees are None when h_tree is None. This module only owns the
         wire mechanics — select/payload/update/scatter all come from the
         shift rule (repro.core.rules), the same arithmetic the simulator
-        drivers run, with the fused diana_shift kernel on the DIANA paths
-        (one pass over four inputs, three outputs, instead of five separate
-        param-sized HBM round-trips).
+        drivers run.
 
         `beta` (None = alpha) is the mean-table stepsize handed to the rule;
         `weight` scales this rank's message into the collective mean (own
         message stays unweighted so the local shift update is unchanged).
+
+        Two paths, chosen by the method and the wire alone:
+
+        window  the shared wire with a `sparse_update` rule ('diana',
+                'diana_rr'): `_window_leaf` reads and writes only the
+                Rand-block window of g, h and H — no dense message leaf is
+                formed, and the tables are written in place.
+        dense   every other method or wire ('q', 'ef', the independent
+                wire): the payload is formed over the whole leaf, the
+                exchange returns dense Q(x) reconstructions and the rule
+                updates whole leaves (the fused diana_shift kernel on
+                'diana' over the independent wire).
         """
         rule = self.rule
+        if h_tree is not None and self._window_path:
+            return self._level_window(grads, h_tree, mh_tree, key, axes=axes,
+                                      fraction=fraction, alpha=alpha,
+                                      beta=beta, idx=idx, weight=weight)
         compress = (self._exchange_shared if self.wire == "shared"
                     else self._exchange_independent)
         leaves, treedef = jax.tree.flatten(grads)
@@ -475,6 +492,109 @@ class CompressedAggregation:
                 jax.tree.unflatten(treedef, new_h),
                 jax.tree.unflatten(treedef, new_mh) if mh_tree is not None
                 else None)
+
+    @property
+    def _window_path(self) -> bool:
+        """Whether the compressed levels take the window-sparse path."""
+        return self.wire == "shared" and self.rule.sparse_update
+
+    def _level_window(self, grads, h_tree, mh_tree, key, *, axes, fraction,
+                      alpha, beta=None, idx=None, weight=None):
+        """`_level` on the window path; same returns, same arithmetic.
+
+        Per leaf, only the circular Rand-block window — the kb*8 rows the
+        shared draw selects — is read from g, h and H, and only it is
+        written back. Outside the window the message is zero, so the rule
+        leaves h and H as they are and the direction is H itself: the one
+        dense pass left is H cast to the gradient's dtype.
+        """
+        rule = self.rule
+        slot = None
+        if rule.slotted:  # PerSlotShift.select's row 0 on slot-free rounds
+            slot = jnp.int32(0) if idx is None else idx[0]
+        leaves, treedef = jax.tree.flatten(grads)
+        dirs, new_h, new_mh = [], [], []
+        for i, (g, ht, mht) in enumerate(zip(leaves, jax.tree.leaves(h_tree),
+                                             jax.tree.leaves(mh_tree))):
+            with jax.named_scope("window"):
+                d, h, mh = self._window_leaf(
+                    g, ht, mht, self._leaf_key(key, i), axes=axes,
+                    fraction=fraction, alpha=alpha, beta=beta, slot=slot,
+                    weight=weight)
+            dirs.append(d)
+            new_h.append(h)
+            new_mh.append(mh)
+        return (jax.tree.unflatten(treedef, dirs),
+                jax.tree.unflatten(treedef, new_h),
+                jax.tree.unflatten(treedef, new_mh))
+
+    def _window_leaf(self, g, ht, mht, key, *, axes, fraction, alpha, beta,
+                     slot, weight):
+        """One leaf of `_level_window` -> (direction, h table, H table).
+
+        The draw, the slab and its exchange are those of `_exchange_shared`
+        (same key, start_block, nb/kb scale after the subtraction, transport
+        and quantization uniforms); the update is the rule's own, in plain
+        jnp (the reference backend) so XLA fuses it with the gathers.
+        """
+        rule = self.rule
+        be = get_backend(self.backend)
+        lead = 0 if slot is None else 1
+        g_rows = self._row_view(g)
+        n = g_rows.shape[0]
+        nb, kb = self._wire_geometry(n + (-n) % BLOCK_ROWS, fraction)
+        start_block = jax.random.randint(key, (), 0, nb)
+        # the window's rows, circular over the block-padded leaf; a padding
+        # row (index >= n) is out of range and reads as zero
+        rows = (start_block * BLOCK_ROWS + jnp.arange(kb * BLOCK_ROWS)) % (
+            nb * BLOCK_ROWS)
+        lead_at = () if slot is None else (slot,)
+
+        def window(x, at=()):
+            return x.at[at + (rows,)].get(mode="fill", fill_value=0)
+
+        h_w = window(self._row_view(ht, lead), lead_at)
+        mh_w = window(self._row_view(mht, lead), lead_at)
+        p_w = rule.payload(window(g_rows).astype(jnp.float32),
+                           h_w.astype(jnp.float32))
+        vals = p_w * (nb / kb)
+        levels = self._quant_levels
+        quant_u = None
+        if levels is not None:
+            qkey = jax.random.fold_in(key, WIRE_QUANT_SALT)
+            quant_u = jax.random.uniform(qkey, vals.shape)
+        own, mean = be.exchange_slab(vals, axes=axes, weight=weight,
+                                     wire_dtype=self.wire_dtype,
+                                     levels=levels, quant_u=quant_u)
+        dir_w, h_w, mh_w = rule.update(h_w, own, mh_w, mean, alpha=alpha,
+                                       beta=beta,
+                                       backend=get_backend("reference"))
+        write = partial(self._write_window, start_block=start_block, nb=nb)
+        new_ht = write(ht, h_w, slot=slot)
+        new_mht = write(mht, mh_w, slot=slot)
+        h_mean = new_mht if slot is None else new_mht[slot]
+        direction = write(h_mean.astype(g.dtype), dir_w, slot=None)
+        return direction, new_ht, new_mht
+
+    def _write_window(self, x, vals, *, start_block, nb, slot):
+        """`x` (a leaf, or with `slot` a slot table) with the window's rows
+        set to `vals`, in place through the backend's write-back kernel.
+        Rows are padded to a block multiple only where they are not one."""
+        be = get_backend(self.backend)
+        lead = 0 if slot is None else 1
+        rows = self._row_view(x, lead)
+        n, cols = rows.shape[-2:]
+        pad = (-n) % BLOCK_ROWS
+        if pad:
+            rows = jnp.pad(rows, [(0, 0)] * lead + [(0, pad), (0, 0)])
+        base = jnp.int32(0) if slot is None else slot * nb
+        flat = be.wire_decompress_into(
+            jnp.reshape(rows, (-1, cols)), vals.astype(x.dtype), start_block,
+            base, n_rows=n + pad, block_rows=BLOCK_ROWS)
+        rows = jnp.reshape(flat, rows.shape)
+        if pad:
+            rows = rows[..., :n, :]
+        return jnp.reshape(rows, x.shape)
 
     # shared-seed Rand-block: sparse collectives -------------------------------
     #
@@ -613,3 +733,27 @@ class CompressedAggregation:
             intra = dense if self.client_axes else 0
             inter = dense if (self.pod_axes and self.pod_size > 1) else 0
         return {"dense": dense, "intra_pod": intra, "inter_pod": inter}
+
+    def wire_paths(self, params) -> dict[str, dict[str, int]]:
+        """Leaves, and their elements, that each wire level runs on the
+        window path and on the dense path of `_level` (its docstring).
+
+        One entry per level that exists, keyed like `wire_bytes_per_round`:
+        'intra_pod' when `client_axes` is set, 'inter_pod' when there is an
+        inter-pod link. Each holds `window_leaves`, `window_elements`,
+        `dense_leaves` and `dense_elements`.
+        """
+        leaves = jax.tree.leaves(params)
+        counts = {"leaves": len(leaves),
+                  "elements": sum(int(np.prod(x.shape)) for x in leaves)}
+        window = self.method != "dense" and self._window_path
+        levels = []
+        if self.client_axes:
+            levels.append("intra_pod")
+        if self.pod_axes and self.pod_size > 1:
+            levels.append("inter_pod")
+        return {level: {f"{path}_{k}": v if (path == "window") == window
+                        else 0
+                        for path in ("window", "dense")
+                        for k, v in counts.items()}
+                for level in levels}

@@ -28,7 +28,9 @@ That polymorphism is free because every rule method is either a
 `jax.tree.map` (works on bare arrays — an array is a pytree) or dispatches
 to the compression backend, which has tree (`tree_diana_shift`, one fused
 kernel launch over the raveled buffer) and flat (`diana_shift_flat`) entry
-points for the same fused DIANA update.
+points for the same fused DIANA update. On the shared wire a
+`sparse_update` rule's `update` gets only the Rand-block window's slabs and
+the reference backend (plain jnp, fused by XLA with the window gathers).
 
 Slot semantics on the wire: every rank of a wire level must use the SAME
 slot in a given round (the mean-shift table update `mh[s] += alpha*q_mean`
@@ -70,6 +72,11 @@ class ShiftRule:
     contractive     the wire must apply the UNSCALED (contractive)
                     compression to this rule's payload (EF diverges under
                     the unbiased d/k-scaled reconstruction)
+    sparse_update   where the compressed message is zero, memory is
+                    unchanged and the direction equals the mean table: the
+                    update is an axpy on the message. The shared wire then
+                    reads and writes only the Rand-block window of the
+                    gradient and the tables (`core.dist`, DESIGN.md §3.5)
     """
 
     name: str = "none"
@@ -79,6 +86,7 @@ class ShiftRule:
     slotted: bool = False
     supports_local: bool = True
     contractive: bool = False
+    sparse_update: bool = False
 
     # -- state layout ---------------------------------------------------------
 
@@ -154,6 +162,7 @@ class SingleShift(ShiftRule):
     has_shifts: bool = True
     has_mean: bool = True
     needs_server_h: bool = True
+    sparse_update: bool = True
 
     def init_shifts(self, params, m=None, *, n_slots=1, dtype=None):
         del n_slots  # analysis: allow[ignored-argument] unslotted: one shift per client

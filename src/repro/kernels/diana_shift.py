@@ -15,6 +15,13 @@ the second stepsize beta = (M/C)*alpha (DESIGN.md §3.10).
 Unfused this is five HBM round-trips over param-sized arrays; the kernel
 streams all four inputs once per (block, 128) VMEM tile and writes the three
 outputs in the same pass.
+
+It runs where the update is dense: the simulator's `tree_diana_shift`, the
+local (NASTYA) family's server `direction`, and 'diana' over the
+independent wire. The shared wire's DIANA update does not call it: there
+the message is zero outside the Rand-block window, so `core.dist` updates
+only the window's rows, in plain jnp, and writes them back in place
+(DESIGN.md §3.5).
 """
 from __future__ import annotations
 

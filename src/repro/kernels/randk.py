@@ -9,6 +9,8 @@ the whole compression is one HBM read of k rows, no index lists.
 
   randk_compress:   rows (N, D), start -> (K, D) * (N/K)   [gather+scale]
   randk_decompress: vals (K, D), start -> (N, D) zeros elsewhere [scatter]
+  randk_decompress_into: vals (K, D), start -> into (R, D), the window's
+                    rows overwritten in place, the rest kept [write-back]
   randk_mask:       x (M, Dp), starts (M,) -> dense Q(x) per client
 
 `randk_mask` is the simulator-side fused compress+decompress (DESIGN.md
@@ -97,6 +99,49 @@ def randk_decompress(vals: jax.Array, start_block: jax.Array, *, n_rows: int,
         out_shape=jax.ShapeDtypeStruct((n_rows, d), vals.dtype),
         interpret=interpret,
     )(start_block.reshape(1).astype(jnp.int32), vals)
+
+
+def _write_kernel(scalars_ref, vals_ref, into_ref, o_ref):
+    del scalars_ref, into_ref  # the index_map places the block; o aliases into
+    o_ref[...] = vals_ref[...].astype(o_ref.dtype)
+
+
+@partial(jax.jit, static_argnames=("n_rows", "block_rows", "interpret"))
+def randk_decompress_into(into: jax.Array, vals: jax.Array,
+                          start_block: jax.Array, base_block: jax.Array, *,
+                          n_rows: int, block_rows: int = BLOCK_ROWS,
+                          interpret: bool | None = None) -> jax.Array:
+    """Write the (K, D) window `vals` into `into` (R, D) in place.
+
+    The window is the circular one `randk_compress` gathers, inside the
+    `n_rows` rows that start at block `base_block` (a slot row of a
+    stacked table; 0 for a plain leaf): block i of `vals` lands on block
+    base_block + (start_block + i) % (n_rows / block_rows). Every other row
+    of `into` keeps its value — the output aliases it, so only the K rows
+    move through VMEM.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    k, d = vals.shape
+    kb = k // block_rows
+    nb = n_rows // block_rows
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(kb,),
+        in_specs=[pl.BlockSpec((block_rows, d), lambda i, s: (i, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((block_rows, d),
+                               lambda i, s: (s[1] + (s[0] + i) % nb, 0)),
+    )
+    scalars = jnp.stack([start_block, base_block]).astype(jnp.int32)
+    return pl.pallas_call(
+        _write_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(into.shape, into.dtype),
+        input_output_aliases={2: 0},
+        interpret=interpret,
+    )(scalars, vals, into)
 
 
 # ---------------------------------------------------------------------------

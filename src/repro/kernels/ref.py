@@ -55,6 +55,21 @@ def randk_decompress_ref(vals: jax.Array, start_block: jax.Array, *,
     return canvas.reshape(n_rows, d)
 
 
+def randk_decompress_into_ref(into: jax.Array, vals: jax.Array,
+                              start_block: jax.Array, base_block: jax.Array,
+                              *, n_rows: int, block_rows: int) -> jax.Array:
+    """Overwrite the circular window of the n_rows-row segment that starts
+    at block `base_block` of `into` (R, D) with `vals` (K, D)."""
+    k, d = vals.shape
+    kb = k // block_rows
+    nb = n_rows // block_rows
+    blocks = into.reshape(-1, block_rows, d)
+    idx = base_block + (start_block + jnp.arange(kb)) % nb
+    blocks = blocks.at[idx].set(vals.reshape(kb, block_rows, d).astype(
+        into.dtype))
+    return blocks.reshape(into.shape)
+
+
 def randk_mask_ref(x: jax.Array, starts: jax.Array, *, d: int, k: int) -> jax.Array:
     """Dense circular-window Rand-k, batched over clients.
 

@@ -483,6 +483,18 @@ def init_state(args, tr: Trainer) -> steps.TrainState:
     return init(salts.root_key(0, salts.PARAMS_KEY_SALT))
 
 
+def record_wire_paths(agg, params) -> None:
+    """Counters `wire.window_leaves` and `wire.dense_leaves`, one of each
+    per wire level (tag `level`, the leaves' element count as tag
+    `elements`): which path of the exchange each level runs
+    (`CompressedAggregation.wire_paths`). Host side, once per run."""
+    for level, paths in agg.wire_paths(params).items():
+        for path in ("window", "dense"):
+            telemetry.counter(f"wire.{path}_leaves", paths[f"{path}_leaves"],
+                              level=level,
+                              elements=paths[f"{path}_elements"])
+
+
 def main(argv=None):
     ap = build_parser()
     args = ap.parse_args(argv)
@@ -501,6 +513,7 @@ def main(argv=None):
             "argv": flags, "arch": tr.cfg.name, "n_params": n_params,
             "mesh_clients": tr.m,
             "wire_bytes_per_round": {k: int(v) for k, v in wire.items()}})
+        record_wire_paths(agg_c, tr.abstract.params)
     try:
         return run(args, tr)
     finally:

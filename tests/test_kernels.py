@@ -16,7 +16,12 @@ from repro.compression.ops import QSGDQuantizer, RandK
 from repro.kernels import ops, ref
 from repro.kernels.diana_shift import diana_shift_update
 from repro.kernels.qsgd import TILE, qsgd_quantize
-from repro.kernels.randk import randk_compress, randk_decompress, randk_mask
+from repro.kernels.randk import (
+    randk_compress,
+    randk_decompress,
+    randk_decompress_into,
+    randk_mask,
+)
 
 REF = CompressionBackend("reference")
 PAL = CompressionBackend("pallas")
@@ -82,6 +87,34 @@ def test_randk_roundtrip_all_starts(n_blocks, k_blocks, d, dtype):
                                           block_rows=br)
         np.testing.assert_allclose(np.asarray(got_d, np.float32),
                                    np.asarray(want_d, np.float32), rtol=1e-2)
+
+
+@pytest.mark.parametrize("n_blocks,k_blocks,slots", [(5, 2, 1), (4, 4, 1),
+                                                      (6, 3, 3)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_randk_decompress_into_matches_ref(n_blocks, k_blocks, slots, dtype):
+    """The in-place write-back touches exactly the window's blocks of the
+    chosen slot segment, for every start (wrap-around included)."""
+    br, d = 8, 16
+    n = n_blocks * br
+    into = jax.random.normal(jax.random.key(0), (slots * n, d)).astype(dtype)
+    vals = jax.random.normal(jax.random.key(1), (k_blocks * br, d))
+    base = jnp.int32((slots - 1) * n_blocks)
+    for start in range(n_blocks):
+        s = jnp.int32(start)
+        got = randk_decompress_into(into, vals, s, base, n_rows=n,
+                                    block_rows=br)
+        want = ref.randk_decompress_into_ref(into, vals, s, base, n_rows=n,
+                                             block_rows=br)
+        assert np.array_equal(np.asarray(got, np.float32),
+                              np.asarray(want, np.float32)), start
+        blocks = np.asarray(want, np.float32).reshape(-1, br, d)
+        touched = {int(base) + (start + i) % n_blocks
+                   for i in range(k_blocks)}
+        kept = [b for b in range(slots * n_blocks) if b not in touched]
+        assert np.array_equal(
+            blocks[kept],
+            np.asarray(into, np.float32).reshape(-1, br, d)[kept])
 
 
 def test_randk_unbiased_over_starts():

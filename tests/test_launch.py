@@ -243,3 +243,128 @@ def test_compile_cache_dir(monkeypatch):
             root / ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+# ---------------------------------------------------------------------------
+# the shared-wire DIANA update is window-sparse (core/dist.py `_level`)
+# ---------------------------------------------------------------------------
+
+# elementwise primitives whose full-leaf f32 output would be a dense pass
+_ELEMENTWISE = {"add", "sub", "mul", "div", "neg", "max", "min", "select_n",
+                "convert_element_type", "integer_pow", "square"}
+
+
+def _trace_wire(method: str, wire: str):
+    """(wire shard_map body jaxprs, configured agg, params) of the tiny
+    StableLM train step on a (4, 1) mesh, traced only."""
+    from repro.analysis.graph import _iter_jaxprs
+
+    cfg = reduced(get_config("stablelm-1.6b"), seq=S)
+    mesh = make_test_mesh((4, 1), ("data", "model"))
+    agg0 = CompressedAggregation(method=method, wire=wire, fraction=0.25,
+                                 n_slots=2 if method == "diana_rr" else 1,
+                                 shift_dtype=jnp.float32)
+    jitted, abstract, _, _ = steps.make_train_step(
+        cfg, mesh, agg=agg0, remat=False, seq_shard=False)
+    batch = {"tokens": jax.ShapeDtypeStruct((8, S + 1), jnp.int32)}
+    key = jax.ShapeDtypeStruct((), jax.eval_shape(jax.random.key, 0).dtype)
+    extra = ([jax.ShapeDtypeStruct((1,), jnp.int32)]
+             if method == "diana_rr" else [])
+    with jax.set_mesh(mesh):
+        jaxpr = jitted.trace(abstract, batch, key, *extra).jaxpr.jaxpr
+    bodies = [list(_iter_jaxprs(getattr(e.params["jaxpr"], "jaxpr",
+                                        e.params["jaxpr"])))
+              for e in jaxpr.eqns if e.primitive.name == "shard_map"
+              and "wire" in str(e.source_info.name_stack)]
+    assert bodies, "no wire region in the traced step"
+    return ([jx for b in bodies for jx in b],
+            steps.configure_agg(agg0, mesh), abstract.params)
+
+
+def _wire_census(method: str, wire: str):
+    """(jitted kernel names, full-leaf f32 elementwise ops, wire_paths) of
+    the wire region. A leaf counts as full where its window is smaller
+    than the leaf; its shape and row view are the dense shapes."""
+    jaxprs, agg, params = _trace_wire(method, wire)
+    dense_shapes = set()
+    for leaf in jax.tree.leaves(params):
+        rows = int(np.prod(leaf.shape[:-1])) if leaf.ndim >= 2 \
+            else leaf.shape[0]
+        nb = -(-rows // 8)
+        if max(1, int(agg.fraction * nb)) < nb:
+            dense_shapes |= {tuple(leaf.shape),
+                             (rows, leaf.shape[-1] if leaf.ndim >= 2 else 1)}
+    names, dense_ops = set(), []
+    for jx in jaxprs:
+        for e in jx.eqns:
+            if e.primitive.name in ("jit", "pjit"):
+                names.add(e.params["name"])
+            if e.primitive.name in _ELEMENTWISE:
+                dense_ops += [e.primitive.name for v in e.outvars
+                              if v.aval.dtype == jnp.float32
+                              and tuple(v.aval.shape) in dense_shapes]
+    return names, dense_ops, agg.wire_paths(params)
+
+
+@pytest.mark.parametrize("method", ["diana", "diana_rr"])
+def test_shared_diana_wire_is_window_sparse(method):
+    """No fused DIANA kernel, no dense scatter and no full-leaf f32
+    elementwise pass in the wire: the window is gathered, exchanged and
+    written back in place (the direction's one dense pass casts to bf16),
+    and the accounting puts every leaf on the window path."""
+    names, dense_ops, paths = _wire_census(method, "shared")
+    assert "randk_decompress_into" in names
+    assert not names & {"diana_shift_update", "randk_compress",
+                        "randk_decompress"}, names
+    assert dense_ops == [], dense_ops
+    (level,) = paths.values()
+    assert level["dense_leaves"] == level["dense_elements"] == 0
+    assert level["window_leaves"] == 15
+
+
+@pytest.mark.parametrize("method,wire,kernel,f32_passes", [
+    ("ef", "shared", "randk_decompress", True),
+    ("q", "shared", "randk_decompress", False),
+    ("diana", "independent", "diana_shift_update", True),
+])
+def test_dense_wires_keep_their_dense_path(method, wire, kernel, f32_passes):
+    """'ef' (dense residual), 'q' and the independent wire keep the dense
+    path: the dense kernels, full-leaf f32 payloads where the rule keeps
+    memory ('q' moves its leaf-dtype message), and the accounting puts
+    every leaf there."""
+    names, dense_ops, paths = _wire_census(method, wire)
+    assert kernel in names, names
+    assert "randk_decompress_into" not in names
+    assert bool(dense_ops) == f32_passes, dense_ops
+    (level,) = paths.values()
+    assert level["window_leaves"] == level["window_elements"] == 0
+    assert level["dense_leaves"] == 15
+
+
+def test_wire_path_counters():
+    """train.py records the accounting once per run, host side: one
+    `wire.window_leaves` and one `wire.dense_leaves` counter per level."""
+    from repro import telemetry
+    from repro.launch import train
+
+    params = {"a": jnp.zeros((16, 8)), "b": jnp.zeros((5,))}
+    mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
+    for method, window in (("diana", 2), ("ef", 0)):
+        agg = steps.configure_agg(
+            CompressedAggregation(method=method, wire="shared"), mesh)
+        sink = telemetry.install(telemetry.MetricsSink())
+        try:
+            train.record_wire_paths(agg, params)
+        finally:
+            telemetry.uninstall()
+        events = [e for e in sink.events() if e["kind"] == "counter"]
+        sink.close()
+        assert telemetry.validate_events(events) == []
+        got = {(e["name"], e["tags"]["level"]): (e["value"],
+                                                 e["tags"]["elements"])
+               for e in events}
+        for level in ("intra_pod", "inter_pod"):
+            assert got[("wire.window_leaves", level)] == (
+                window, 133 if window else 0)
+            assert got[("wire.dense_leaves", level)] == (
+                2 - window, 0 if window else 133)

@@ -230,6 +230,223 @@ def test_packed_wire_byte_accounting(mesh_4x2):
 
 
 # ---------------------------------------------------------------------------
+# window path: the shared-wire DIANA update reads and writes only the
+# Rand-block window (core/dist.py `_level_window`) — parity with a dense
+# oracle built from the reference functions
+# ---------------------------------------------------------------------------
+
+# per-client leaves: rows not a multiple of 8 (30, and 3*12 = 36), a 1-D
+# leaf, a bf16 gradient; "w" (30 rows, 4 blocks) is the one whose window
+# start each case pins
+WIN_SHAPES = {"b": ((20,), jnp.float32), "v": ((3, 12, 16), jnp.bfloat16),
+              "w": ((30, 16), jnp.float32)}
+WIN_LEAF = 2  # "w" in flatten order
+WIN_CASES = [
+    # method, mesh, elastic, transport, window start of "w", shift dtype
+    ("diana", "flat", False, "f32", "first", "f32"),
+    ("diana", "flat", False, "f32", "mid", "f32"),
+    ("diana", "flat", False, "f32", "wrap", "f32"),
+    ("diana", "flat", False, "f32", "full", "f32"),
+    ("diana", "flat", True, "f32", "wrap", "bf16"),
+    ("diana", "flat", False, "f32+levels", "mid", "f32"),
+    ("diana", "flat", True, "bf16", "wrap", "f32"),
+    ("diana", "flat", False, "packed8", "first", "bf16"),
+    ("diana", "pods", False, "f32", "wrap", "f32"),
+    ("diana", "pods", True, "packed8", "mid", "f32"),
+    ("diana", "pods", False, "bf16", "full", "bf16"),
+    ("diana", "pods", True, "f32+levels", "first", "bf16"),
+    ("diana_rr", "flat", False, "f32", "mid", "f32"),
+    ("diana_rr", "flat", True, "f32+levels", "wrap", "bf16"),
+    ("diana_rr", "flat", False, "packed8", "full", "f32"),
+    ("diana_rr", "pods", False, "f32", "first", "bf16"),
+    ("diana_rr", "pods", True, "bf16", "wrap", "f32"),
+    ("diana_rr", "pods", False, "f32+levels", "full", "f32"),
+]
+
+
+def _ulps(got, want, dtype, old):
+    """|got - want| in units in the last place at `dtype` of the largest
+    term of want = old + step: |want|, |old| or |step| (elementwise)."""
+    info = jnp.finfo(dtype)
+    got, want, old = (np.asarray(x, np.float32) for x in (got, want, old))
+    big = np.maximum(np.maximum(np.abs(want), np.abs(old)),
+                     np.abs(want - old))
+    exp = np.frexp(big)[1] - 1
+    ulp = np.maximum(np.ldexp(1.0, exp - info.nmant),
+                     float(info.smallest_subnormal))
+    return np.abs(got - want) / ulp
+
+
+def _oracle_level(agg, grads, h_tree, mh_tree, key, *, axes, fraction,
+                  alpha, beta, slot, weight):
+    """One exchange level the dense way: payload over the whole leaf, the
+    slab through the backend's (unchanged) transport, dense Q(x) from
+    `randk_decompress_ref`, the update by `diana_shift_update_ref`."""
+    from repro.compression.backend import BLOCK_ROWS as BR
+    from repro.compression.backend import get_backend
+    from repro.core.salts import WIRE_QUANT_SALT
+    from repro.kernels import ref
+
+    be = get_backend(agg.backend)
+    dirs, hs, mhs = [], [], []
+    leaves, treedef = jax.tree.flatten(grads)
+    for i, (g, ht, mht) in enumerate(zip(leaves, jax.tree.leaves(h_tree),
+                                         jax.tree.leaves(mh_tree))):
+        h = ht if slot is None else ht[slot]
+        mh = mht if slot is None else mht[slot]
+        cols = g.shape[-1] if g.ndim >= 2 else 1
+        p = (g.astype(jnp.float32) - h.astype(jnp.float32)).reshape(-1, cols)
+        n = p.shape[0]
+        p = jnp.pad(p, ((0, (-n) % BR), (0, 0)))
+        nb = p.shape[0] // BR
+        kb = max(1, int(fraction * nb))
+        lkey = jax.random.fold_in(key, i)
+        start = jax.random.randint(lkey, (), 0, nb)
+        levels = agg._quant_levels
+        quant_u = None if levels is None else jax.random.uniform(
+            jax.random.fold_in(lkey, WIRE_QUANT_SALT), (kb * BR, cols))
+        own, mean = be.wire_exchange(p, start, k_blocks=kb, block_rows=BR,
+                                     axes=axes, weight=weight,
+                                     wire_dtype=agg.wire_dtype,
+                                     levels=levels, quant_u=quant_u)
+        dense = lambda v: ref.randk_decompress_ref(
+            v, start, n_rows=nb * BR, block_rows=BR)[:n].reshape(g.shape)
+        d, h_new, mh_new = ref.diana_shift_update_ref(
+            h, dense(own), mh, dense(mean), alpha, beta)
+        dirs.append(d.astype(g.dtype))
+        hs.append(h_new if slot is None else ht.at[slot].set(h_new))
+        mhs.append(mh_new if slot is None else mht.at[slot].set(mh_new))
+    return tuple(jax.tree.unflatten(treedef, x) for x in (dirs, hs, mhs))
+
+
+def _window_rows(key, leaf: int, n: int, fraction: float) -> np.ndarray:
+    """Rows of an n-row leaf inside the level's window (host side)."""
+    nb = -(-n // 8)
+    kb = max(1, int(fraction * nb))
+    start = int(jax.random.randint(jax.random.fold_in(key, leaf), (), 0,
+                                   nb))
+    return np.asarray(sorted({(start * 8 + j) % (nb * 8)
+                              for j in range(kb * 8)} & set(range(n))))
+
+
+@pytest.mark.parametrize("method,mesh_name,elastic,transport,where,sdt",
+                         WIN_CASES)
+def test_window_path_matches_dense_oracle(method, mesh_name, elastic,
+                                          transport, where, sdt, mesh_4x2,
+                                          mesh_2x2x2):
+    """diana/diana_rr on the shared wire update only the window: inside it
+    h, H and the direction match the dense oracle within 1 ulp (bitwise
+    where XLA contracts nothing); outside it h and H are bitwise their old
+    values and the direction is the mean table cast to the gradient dtype.
+    Flat and two-level meshes, elastic weights, every transport, window
+    starts at block 0, mid, the wrap-around last block and k/d = 1, rows
+    not a multiple of 8, a 1-D leaf, f32 and bf16 shift tables."""
+    from repro.core.salts import POD_KEY_SALT
+
+    mesh = mesh_4x2 if mesh_name == "flat" else mesh_2x2x2
+    shift_dtype = jnp.float32 if sdt == "f32" else jnp.bfloat16
+    n_slots = 3 if method == "diana_rr" else 1
+    slot = 1 if method == "diana_rr" else None
+    fraction = 1.0 if where == "full" else 0.5
+    agg = _configure(CompressedAggregation(
+        method=method, wire="shared", fraction=fraction, n_slots=n_slots,
+        shift_dtype=shift_dtype,
+        wire_dtype="f32" if transport == "f32+levels" else transport,
+        wire_levels=127 if transport == "f32+levels" else None), mesh)
+    assert agg._window_path
+    target = {"first": 0, "mid": 2, "wrap": 3, "full": 0}[where]
+    seed = next(s for s in range(1000) if int(jax.random.randint(
+        jax.random.fold_in(jax.random.PRNGKey(s), WIN_LEAF), (), 0, 4))
+        == target)
+    key = jax.random.PRNGKey(seed)
+
+    caxes = tuple(n for n in mesh.axis_names if n != "model")
+    m = 4
+    lead = (n_slots,) if slot is not None else ()
+    ks = iter(jax.random.split(jax.random.PRNGKey(100 + seed), 16))
+    grads = {k: jax.random.normal(next(ks), (m,) + s).astype(d)
+             for k, (s, d) in WIN_SHAPES.items()}
+    tables = [{k: (0.3 * jax.random.normal(next(ks), (m,) + lead + s)
+                   ).astype(shift_dtype) for k, (s, _) in WIN_SHAPES.items()}
+              for _ in range(4)]
+    weights = jnp.asarray([0.5, 1.0, 0.0, 2.0], jnp.float32)
+
+    def spec(x, extra=0):
+        tp = "model" if x.ndim - 1 - extra >= 2 else None
+        return P(caxes, *(None,) * (x.ndim - 2), tp)
+
+    gspec = jax.tree.map(spec, grads)
+    tspec = jax.tree.map(lambda x: spec(x, len(lead)), tables[0])
+    pods = bool(agg.pod_axes)
+
+    def body(g, h, mh, ph, pmh, w):
+        one = lambda t: jax.tree.map(lambda x: x[0], t)
+        g, h, mh, ph, pmh = map(one, (g, h, mh, ph, pmh))
+        wt = w[0] if elastic else None
+        state = DianaState(h, mh, ph if pods else None,
+                           pmh if pods else None)
+        got_d, st = agg.aggregate(g, state, key, slot=slot, weight=wt)
+        got = (got_d, st.shifts, st.mean_shift,
+               st.pod_shifts if pods else ph,
+               st.pod_mean_shift if pods else pmh)
+        d, h2, mh2 = _oracle_level(
+            agg, g, h, mh, key, axes=agg.client_axes, fraction=fraction,
+            alpha=agg.shift_lr, beta=agg._beta(agg.shift_lr), slot=slot,
+            weight=wt)
+        ph2, pmh2 = ph, pmh
+        if pods:
+            d, ph2, pmh2 = _oracle_level(
+                agg, d, ph, pmh, jax.random.fold_in(key, POD_KEY_SALT),
+                axes=agg.pod_axes, fraction=agg._pod_fraction,
+                alpha=agg.pod_shift_lr, beta=None, slot=slot, weight=None)
+        want = (d, h2, mh2, ph2, pmh2)
+        return jax.tree.map(lambda x: x[None], (got, want))
+
+    specs = (gspec, tspec, tspec, tspec, tspec)
+    f = jax.jit(_shard_map(body, mesh, specs + (P(caxes),),
+                           (specs, specs)))
+    got, want = f(grads, *tables, weights)
+
+    sel = (lambda x: x) if slot is None else (lambda x: x[:, slot])
+    levels = [(key, 1, 2, tables[0], tables[1])]  # (key, h, H, old h, old H)
+    if pods:
+        levels.append((jax.random.fold_in(key, POD_KEY_SALT), 3, 4,
+                       tables[2], tables[3]))
+    for name, (shape, gdt) in WIN_SHAPES.items():
+        i = sorted(WIN_SHAPES).index(name)
+        n = int(np.prod(shape[:-1])) if len(shape) >= 2 else shape[0]
+        # each output is old + step: its ulp is taken at the largest term,
+        # so a step that cancels the old value is not held to the ulp of
+        # the small remainder (the direction's old value is the last
+        # level's mean table)
+        before = [tables[3 if pods else 1][name]] + [t[name]
+                                                     for t in tables]
+        before[0] = sel(before[0])
+        for j in range(5):
+            dt = gdt if j == 0 else shift_dtype
+            err = _ulps(got[j][name], want[j][name], dt, before[j])
+            assert err.max() <= 1.0, (name, j, err.max())
+        for lkey, hi, mhi, old_h, old_mh in levels:
+            rows = _window_rows(lkey, i, n, fraction)
+            outside = np.setdiff1d(np.arange(n), rows)
+            as_rows = lambda x: np.asarray(sel(x), np.float32).reshape(
+                m, n, -1)
+            for j, old in ((hi, old_h), (mhi, old_mh)):
+                assert np.array_equal(as_rows(got[j][name])[:, outside],
+                                      as_rows(old[name])[:, outside]), (
+                    name, j)
+        last_mh = np.asarray(
+            sel(tables[3 if pods else 1][name]).astype(gdt), np.float32)
+        assert np.array_equal(
+            np.asarray(got[0][name], np.float32).reshape(m, n, -1)[
+                :, outside],
+            last_mh.reshape(m, n, -1)[:, outside]), name
+        if where == "wrap" and name == "w":
+            assert set(_window_rows(key, i, n, fraction)) == set(
+                range(24, 30)) | set(range(8))
+
+
+# ---------------------------------------------------------------------------
 # statistics: unbiased, composed variance bound (1+w1)(1+w2)
 # ---------------------------------------------------------------------------
 

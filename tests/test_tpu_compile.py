@@ -10,6 +10,7 @@ Shapes are row views of the chip-share config's leaves at the paper's
 k/d = 0.02: the embedding / LM head (100352, 2048) puts a 2000-row slab on
 the wire, an MLP leaf (16384, 5632) a 320-row slab, and a single-layer MLP
 leaf (2048, 5632) a 40-row slab — five 8-row blocks, an odd count.
+The window write-back writes such slabs into whole leaves and slot rows.
 """
 import os
 
@@ -21,7 +22,12 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.diana_shift import diana_shift_update
 from repro.kernels.pack import pack_slab, unpack_reduce, unpack_slab
 from repro.kernels.qsgd import qsgd_quantize
-from repro.kernels.randk import randk_compress, randk_decompress, randk_mask
+from repro.kernels.randk import (
+    randk_compress,
+    randk_decompress,
+    randk_decompress_into,
+    randk_mask,
+)
 
 F32, BF16, U8 = jnp.float32, jnp.bfloat16, jnp.uint8
 
@@ -67,6 +73,20 @@ def test_randk_decompress(one_chip, n, d, kb, dtype):
     compile_for_chip(
         lambda vals, s: randk_decompress(vals, s, n_rows=n, interpret=False),
         one_chip, ((kb * 8, d), dtype), ((), jnp.int32))
+
+
+@pytest.mark.parametrize("r,n,d,kb", [(100352, 100352, 2048, 250),
+                                      (8 * 2048, 2048, 5632, 5),
+                                      (2048, 2048, 1, 5)])
+@pytest.mark.parametrize("dtype", [F32, BF16])
+def test_randk_decompress_into(one_chip, r, n, d, kb, dtype):
+    """The window write-back, in place: a plain leaf, one slot row of a
+    stacked table, and a 1-D leaf's (rows, 1) view."""
+    compile_for_chip(
+        lambda into, vals, s, b: randk_decompress_into(
+            into, vals, s, b, n_rows=n, interpret=False),
+        one_chip, ((r, d), dtype), ((kb * 8, d), F32), ((), jnp.int32),
+        ((), jnp.int32))
 
 
 def test_randk_mask(one_chip):
