@@ -3,8 +3,10 @@ measured window, the reference comparison.
 
 A cell is a configuration file (`configs/<config>.json`) under a traffic
 mix (`traffic/<traffic>.json`), with the limits of its comparison in
-`limits/<workload>.json`. The trainer is assembled as the program's own
-`launch/train.py::build` assembles it: the mesh of the attached chips,
+`limits/<workload>.json`; the configuration file names its reference
+model, `references/<reference>.py` (`reference_model`). The trainer is
+assembled as the program's own `launch/train.py::build` assembles it: the
+mesh of the attached chips,
 `CompressedAggregation` with float32 shifts, and `steps.make_train_step`
 with full remat. Full participation is fed by `data.pipeline`'s batch
 stream, the fleet by `fleet.FleetRunner` over a host `ClientStateStore`
@@ -20,9 +22,11 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import importlib.util
 import json
 import math
 import time
+from pathlib import Path
 from types import SimpleNamespace
 
 import jax
@@ -30,8 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 
 import compare
-import flops
 import reference
+import scopes
 import tokens
 import weights
 
@@ -39,11 +43,31 @@ WARM_ROUNDS = 3  # rounds the reference follows; at least one whole round
 N_BATCHES = 8  # batches per client in the RR data set, as train.py keeps
 WEIGHT_SALT, ROUND_SALT, TOKEN_SALT = 1, 2, 3
 FAULTS = ("unchanged", "half_batch")
+REFERENCES = Path(__file__).resolve().parent / "references"
 
 
 def load_json(path) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def reference_model(conf: dict):
+    """The reference model module a configuration names: `"reference":
+    "<name>"` loads `references/<name>.py`, which exports
+    `param_shapes(m)`, `loss(params, tokens, m, fp8=False)` and
+    `flops_per_token(m, seq)`. There is no default."""
+    name = conf.get("reference")
+    if name is None:
+        raise ValueError(f"{conf['name']}: the configuration names no "
+                         "reference model (key 'reference')")
+    path = REFERENCES / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"{conf['name']}: reference model {name!r} does "
+                         f"not exist (no {path})")
+    spec = importlib.util.spec_from_file_location(f"reference_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def arch_config(conf: dict):
@@ -213,6 +237,7 @@ def run(conf: dict, traffic: dict, limits: dict, *, seed: int,
     from repro.fleet import ClientStateStore, CohortSampler, FleetRunner
     from repro.launch import steps
 
+    model = reference_model(conf)
     cfg = arch_config(conf)
     mesh = Mesh(np.asarray(devices).reshape(len(devices), 1),
                 ("data", "model"))
@@ -275,9 +300,13 @@ def run(conf: dict, traffic: dict, limits: dict, *, seed: int,
             prog["change"] = np.asarray(weights.init_distance(
                 wkey, state.params)).tolist()
             feeds = [np.asarray(r).reshape(m, b, seq + 1) for r in feed.rows]
+            op_scopes = phase_map_s = None
             if trace_dir is not None:
-                if tr.fleet:
-                    sink = telemetry.install(telemetry.MetricsSink())
+                t_map = time.perf_counter()
+                op_scopes = scopes.op_scopes(
+                    scopes.step_hlo(tr, feed.rows[-1], rkey))
+                phase_map_s = time.perf_counter() - t_map
+                sink = telemetry.install(telemetry.MetricsSink())
                 jax.profiler.start_trace(str(trace_dir))
             window_start = time.perf_counter()
             if tr.fleet:
@@ -305,10 +334,8 @@ def run(conf: dict, traffic: dict, limits: dict, *, seed: int,
         "rounds": rounds, "round_s": times,
         "tokens": rounds * m * b * seq, "chips": len(devices),
         "memory_peak_bytes": int(peak), "spans": spans,
-        "flops_per_token": flops.model_flops_per_token(conf["model"], seq),
-        "kernel_bytes_per_round": {
-            "diana_shift": flops.diana_shift_bytes(
-                [x.shape for x in jax.tree.leaves(tr.abstract.params)])},
+        "op_scopes": op_scopes, "phase_map_s": phase_map_s,
+        "flops_per_token": model.flops_per_token(conf["model"], seq),
         "finite": bool(np.isfinite(loss_end)),
     }
     del state, feed
@@ -318,9 +345,9 @@ def run(conf: dict, traffic: dict, limits: dict, *, seed: int,
         del stream
     gc.collect()
     ref = reference.run(
-        conf["model"], wkey, rkey, feeds, fraction=traffic["fraction"],
-        lr=traffic["lr"], alpha=tr.alpha, beta=tr.beta,
-        fresh_clients=tr.fleet)
+        model, conf["model"], wkey, rkey, feeds,
+        fraction=traffic["fraction"], lr=traffic["lr"], alpha=tr.alpha,
+        beta=tr.beta, fresh_clients=tr.fleet)
     record["gaps"] = compare.gaps(prog, ref)
     record["correct"], record["checks"] = compare.judge(record["gaps"],
                                                         limits)

@@ -7,14 +7,11 @@ import json
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
-MODEL_KEYS = ("family", "attention_mixer", "num_layers", "d_model",
-              "num_heads", "num_kv_heads", "head_dim", "d_ff", "vocab",
-              "norm", "act", "qkv_bias", "rope_theta", "sliding_window",
-              "num_experts", "tie_embeddings")
 
 
 def tiny_config(name: str) -> dict:
-    """The configuration file `name` with its widths cut to toy size."""
+    """The configuration file `name` with its widths cut to toy size, naming
+    the same reference model."""
     import jax.numpy as jnp
 
     from repro.configs import get_config
@@ -27,10 +24,10 @@ def tiny_config(name: str) -> dict:
     if conf["model"]["sliding_window"]:
         over["sliding_window"] = 16
     cfg = dataclasses.replace(get_config(conf["arch"]), **over)
-    model = {k: getattr(cfg, k) for k in MODEL_KEYS}
+    model = {k: getattr(cfg, k) for k in conf["model"]}
     model["dtype"] = jnp.dtype(cfg.dtype).name
     return {"name": f"tiny-{name}", "arch": conf["arch"], "overrides": over,
-            "model": model}
+            "model": model, "reference": conf.get("reference")}
 
 
 def tiny_traffic(name: str) -> dict:

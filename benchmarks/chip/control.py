@@ -7,9 +7,10 @@ readings), all in one process.
         --seeds 11,12,13
 
 Per seed it runs the cell as `run.py` does with the shortest window, then
-the reference twice more on the same rows and keys: with every matmul on
-float8 e4m3 operands (the control: one precision below the configuration's
-bfloat16), and with half of each client's rows left out (the fault). A
+the reference twice more on the same rows and keys: with every matmul of
+the configuration's reference model on float8 e4m3 operands (the control:
+one precision below the configuration's bfloat16), and with half of each
+client's rows left out (the fault). A
 state left unchanged reads 1 on grad, shift and change by construction and
 needs no run. The benchmark's own runs never run this.
 """
@@ -32,10 +33,11 @@ def readings(conf, traffic, limits, seed, devices) -> dict:
     rec = cell.run(conf, traffic, limits, seed=seed, seconds=0.0,
                    devices=devices, t_start=time.perf_counter())
     ref = rec["readings"]["reference"]
+    model = cell.reference_model(conf)
     out = {"program": rec["gaps"]}
     for name, kw in VARIANTS.items():
-        got = reference.run(conf["model"], *rec["keys"], rec["feeds"],
-                            **rec["wire"], **kw)
+        got = reference.run(model, conf["model"], *rec["keys"],
+                            rec["feeds"], **rec["wire"], **kw)
         out[name] = compare.gaps(got, ref)
     return out
 

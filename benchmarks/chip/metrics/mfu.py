@@ -1,6 +1,7 @@
 """Model FLOPs of the tokens trained in the window over the window's
-seconds, the chips and the chip's peak bf16 FLOP/s, in % (the FLOP
-convention is in flops.py: no recomputation counted)."""
+seconds, the chips and the chip's peak bf16 FLOP/s, in %. The FLOPs per
+token are the configuration's reference model's `flops_per_token`
+(`references/<name>.py`), which counts no recomputation."""
 
 
 def read(record, trace):

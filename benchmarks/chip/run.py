@@ -146,6 +146,8 @@ def main(argv=None) -> int:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
     out["checks"] = record["checks"]
+    if record["phase_map_s"] is not None:
+        print(f"phase_map_s {record['phase_map_s']!r}", file=sys.stderr)
     print("readings " + json.dumps(record["readings"]), file=sys.stderr)
     print("gaps " + json.dumps(record["gaps"]), file=sys.stderr)
     for name, c in record["checks"].items():

@@ -35,3 +35,22 @@ def test_sound_run_is_correct(workload):
 def test_fault_is_caught(workload, fault):
     rec = run_tiny(workload, fault)
     assert not rec["correct"], rec["checks"]
+
+
+def test_reference_model_is_found_by_name(tmp_path, monkeypatch):
+    """A copy of `references/dense.py` under another name, named by the
+    configuration, gives the same readings and gaps as `dense` itself."""
+    conf, traffic = CASES["stablelm.diana.full"]
+    args = dict(traffic=tiny_traffic(traffic), limits=TINY_LIMITS,
+                seed=2 ** 33 + 19, seconds=0.0, devices=jax.devices()[:1])
+    base = tiny_config(conf)
+    got = cell.run(base, t_start=time.perf_counter(), **args)
+    (tmp_path / "plain_copy.py").write_text(
+        (cell.REFERENCES / "dense.py").read_text())
+    monkeypatch.setattr(cell, "REFERENCES", tmp_path)
+    moved = cell.run({**base, "reference": "plain_copy"},
+                     t_start=time.perf_counter(), **args)
+    assert moved["correct"], moved["checks"]
+    assert moved["readings"] == got["readings"]
+    assert moved["gaps"] == got["gaps"]
+    assert moved["flops_per_token"] == got["flops_per_token"]

@@ -112,27 +112,24 @@ def test_tiny_step_names_its_phases(tiny_step_hlo):
 
 
 def test_traced_tiny_run_puts_program_spans_in_the_window(tmp_path):
-    """With a sink installed around a traced cell run, the profiler's host
-    plane carries the program's batch spans inside the window, where
-    `spans.host_spans` finds them; the CPU has no TPU plane to meet them
-    with."""
+    """A traced cell run installs a sink, so the profiler's host plane
+    carries the program's batch spans inside the window, where
+    `spans.host_spans` finds them, and keeps the compiled step's phase map;
+    the CPU has no TPU plane to meet the spans with."""
     from jax.profiler import ProfileData
 
     from repro import telemetry
 
-    sink = telemetry.install(telemetry.MetricsSink())
-    try:
-        rec = cell.run(tiny_config("stablelm-1.6b-chip"),
-                       tiny_traffic("diana.full"), TINY_LIMITS,
-                       seed=2 ** 33 + 23, seconds=0.0,
-                       devices=jax.devices()[:1],
-                       t_start=time.perf_counter(), trace_dir=tmp_path)
-    finally:
-        telemetry.uninstall()
+    rec = cell.run(tiny_config("stablelm-1.6b-chip"),
+                   tiny_traffic("diana.full"), TINY_LIMITS,
+                   seed=2 ** 33 + 23, seconds=0.0,
+                   devices=jax.devices()[:1],
+                   t_start=time.perf_counter(), trace_dir=tmp_path)
     assert rec["correct"], rec["checks"]
-    assert {"input_wait", "assemble"} <= {
-        e["name"] for e in sink.events() if e.get("kind") == "span"}
-    sink.close()
+    assert not telemetry.enabled()
+    assert {"input_wait", "assemble"} <= set(rec["spans"])
+    assert {"client_grads/remat", "wire", "server_update"} <= set(
+        rec["op_scopes"].values())
     planes = list(ProfileData.from_file(
         str(xtrace.find_xplane(tmp_path))).planes)
     (t0, t1), found = spans.host_spans(planes)
@@ -170,7 +167,9 @@ def test_idle_in_span_matches_brute_force(seed):
             inside[max(s, t0) - t0:max(min(e, t1) - t0, 0)] = True
         assert got[name] == pytest.approx(int((idle & inside).sum()) * 1e-9)
     busy = brute_busy(ops, t0, t1)
-    assert xtrace.reduce([host, chip])["busy_s"] == pytest.approx(busy * 1e-9)
+    reduced = xtrace.reduce([host, chip])
+    assert reduced["busy_s"] == pytest.approx(busy * 1e-9)
+    assert reduced["chips"][0]["idle_in_span"] == got
 
 
 def test_idle_in_span_without_program_spans():

@@ -14,7 +14,8 @@ events of the "XLA Ops" line are the device's operations and those of
 - exposed collective time: the part of collective operations' intervals
   that no other operation on that chip covers;
 - the ten longest idle gaps, each labelled with the innermost harness annotation on the
-  host that spans the gap's midpoint ("untraced" when none does).
+  host that spans the gap's midpoint ("untraced" when none does);
+- idle time inside each of the program's spans (`spans.idle_in_span`).
 """
 from __future__ import annotations
 
@@ -114,6 +115,8 @@ def chip_numbers(lines: dict, t0: int, t1: int, labels) -> dict:
 
 def reduce(planes) -> dict:
     """`planes`: the trace's planes (ProfileData.planes)."""
+    from spans import idle_in_span
+
     planes = list(planes)
     window, labels, chips = None, [], {}
     for plane in planes:
@@ -136,6 +139,8 @@ def reduce(planes) -> dict:
     if not chips:
         raise ValueError("no TPU device plane in the trace")
     per_chip = [chips[k] for k in sorted(chips)]
+    for c, idle in zip(per_chip, idle_in_span(planes)):
+        c["idle_in_span"] = idle
     ops = {}
     for c in per_chip:
         for k, v in c["op_s"].items():
