@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs + reduced smoke variants.
+"""Architecture registry: the 11 configs + reduced smoke variants.
 
 Usage:
     cfg = get_config("deepseek-67b")
@@ -29,6 +29,7 @@ _MODULES = {
     "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
     "whisper-medium": "whisper_medium",
     "dbrx-132b": "dbrx_132b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 ARCH_NAMES = tuple(_MODULES)
@@ -62,7 +63,9 @@ def all_configs() -> dict[str, ArchConfig]:
 
 def reduced(cfg: ArchConfig, *, seq: int = 64) -> ArchConfig:
     """Same-family reduced variant for CPU smoke tests:
-    2 layers, d_model <= 512, <= 4 experts, tiny vocab/window."""
+    2 layers (one of them dense where the model leads with dense layers),
+    d_model <= 512, <= 4 experts held (of 8 router outputs where the
+    router is wider than the experts held), tiny vocab/window/latent."""
     heads = 4
     head_dim = 32
     d_model = heads * head_dim  # 128 — rwkv needs d % heads == 0
@@ -81,6 +84,14 @@ def reduced(cfg: ArchConfig, *, seq: int = 64) -> ArchConfig:
         changes.update(num_experts=4, experts_per_token=2)
         if cfg.shared_expert_ff:
             changes.update(shared_expert_ff=128)
+        if cfg.router_experts:
+            # the router's outputs outnumber the experts held, as on a chip
+            changes.update(router_experts=8)
+    if cfg.attention_mixer == "mla":
+        changes.update(kv_lora_rank=64, qk_nope_head_dim=32,
+                       qk_rope_head_dim=16, v_head_dim=32)
+    if cfg.first_dense_layers:
+        changes.update(first_dense_layers=1, dense_d_ff=384)
     if cfg.ssm_state:
         changes.update(ssm_state=8, ssm_heads=heads)
     if cfg.sliding_window:

@@ -10,6 +10,7 @@ Layout (DESIGN.md §5) — Megatron-style TP over "model", clients over
                                            axis when H doesn't divide the
                                            model axis (hymba's 25 heads)
   norms / router / small vectors        -> replicated
+  MLA: latent projection wkv_a          -> replicated; wkv_b column-parallel
   DIANA shifts (M, *param)              -> ("pod","data") on axis 0 + param spec
   batches                               -> axis 0 over ("pod","data")
 
@@ -26,15 +27,19 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# last-axis column-parallel weights (and their biases)
+# last-axis column-parallel weights (and their biases); wkv_b expands the
+# latent into the heads' keys and values
 _COL = {
     "wq", "wk", "wv", "wx", "wbc", "wdt", "wr", "wg", "w_up", "w_gate", "wA",
-    "bq", "bk", "bv", "b_up", "w0", "mu",
+    "bq", "bk", "bv", "b_up", "w0", "mu", "wkv_b",
 }
 # axis -2 row-parallel weights / per-head (H, hd) tensors
 _ROW = {"wo", "w_down", "wo_fused", "wB", "u", "ln", "ln_attn", "ln_out"}
 _VOCAB = {"embed", "lm_head"}
-_REPLICATED = {"router", "scale", "bias", "a_log", "pos_embed"}
+# wkv_a projects to the latent every head reads; b_router is the router's
+# selection bias
+_REPLICATED = {"router", "b_router", "scale", "bias", "a_log", "pos_embed",
+               "wkv_a"}
 
 
 def _model_size(mesh) -> int:

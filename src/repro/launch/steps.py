@@ -305,8 +305,11 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
     server_lr = (eta if eta is not None else gamma * local_steps) \
         if local_steps > 1 else lr
     opt = _make_optimizer(optimizer, server_lr)
+    # MoE configurations also return the routed-row counts of the round
+    moe_stats = bool(cfg.num_experts)
     loss_fn = partial(transformer.loss_fn, cfg=cfg, remat=remat,
-                      unroll=unroll, ce=ce, seq_shard=seq_shard)
+                      unroll=unroll, ce=ce, seq_shard=seq_shard,
+                      with_stats=moe_stats)
     stateful = agg.rule.has_shifts  # diana / diana_rr / ef keep wire memory
     slotted = agg.rule.slotted
     nslots = agg.n_slots if slotted else 0  # 0 = tables carry no slot axis
@@ -357,11 +360,21 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
     stack_pod = stack if pod_axis else (lambda t: t)
 
     def grads_and_loss(params_stacked, batch_c):
-        """Per-client (loss, grad) under GSPMD: vmap over the client dim."""
+        """Per-client (loss, grad, MoE row counts) under GSPMD: vmap over
+        the client dim. The counts, summed over the clients (`local_rows`)
+        or maxed (`max_expert_rows`), are {} without experts."""
         with jax.named_scope("client_grads"):
-            return jax.vmap(
-                lambda p, b: jax.value_and_grad(loss_fn)(p, b)
+            if not moe_stats:
+                losses, g = jax.vmap(
+                    lambda p, b: jax.value_and_grad(loss_fn)(p, b)
+                )(params_stacked, batch_c)
+                return losses, g, {}
+            (losses, st), g = jax.vmap(
+                lambda p, b: jax.value_and_grad(loss_fn, has_aux=True)(p, b)
             )(params_stacked, batch_c)
+            return losses, g, {
+                "moe_local_rows": jnp.sum(st["moe_local_rows"]),
+                "moe_max_expert_rows": jnp.max(st["moe_max_expert_rows"])}
 
     def broadcast_clients(tree):
         """params -> (M, *shape) client-stacked view (replication, no copy
@@ -520,7 +533,7 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
                 jax.tree.map(
                     lambda p: jnp.repeat(p, clients_per_pod, axis=0), x),
                 jax.tree.map(lambda s: NamedSharding(mesh, s), stacked_specs))
-            losses, g = grads_and_loss(x_clients, batch_j)
+            losses, g, stats = grads_and_loss(x_clients, batch_j)
             kd = jax.random.key_data(
                 jax.random.fold_in(rkey, salts.NASTYA_LOCAL_SALT + t))
             with jax.named_scope("wire"):
@@ -530,9 +543,9 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
                 lambda xi, d: (xi.astype(jnp.float32)
                                - gamma * d.astype(jnp.float32)
                                ).astype(xi.dtype), x, direction)
-            return (x, shifts, mean_shift), jnp.mean(losses)
+            return (x, shifts, mean_shift), (jnp.mean(losses), stats)
 
-        (x_pods, new_shifts, new_ms), losses = lax.scan(
+        (x_pods, new_shifts, new_ms), (losses, stats) = lax.scan(
             body, (x_pods, state.shifts, state.mean_shift),
             (xs, slot_cols, jnp.arange(local_steps)))
 
@@ -550,6 +563,10 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
             for x in jax.tree.leaves(g_pod)) / n_pods_)
         extras = (_debug_extras(g_pod, direction, new_shifts, new_ms)
                   if debug_metrics else {})
+        if stats:
+            extras.update(
+                moe_local_rows=jnp.sum(stats["moe_local_rows"]),
+                moe_max_expert_rows=jnp.max(stats["moe_max_expert_rows"]))
         return (direction, new_shifts, new_ms, new_psh, new_pms,
                 jnp.mean(losses), gnorm, extras)
 
@@ -558,7 +575,8 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
         bsz = jax.tree.leaves(batch)[0].shape[0] // m
         batch_c = jax.tree.map(
             lambda x: x.reshape((m, bsz) + x.shape[1:]), batch)
-        losses, g = grads_and_loss(broadcast_clients(state.params), batch_c)
+        losses, g, stats = grads_and_loss(broadcast_clients(state.params),
+                                          batch_c)
         with jax.named_scope("wire"):
             direction, new_shifts, new_ms, new_psh, new_pms = full_wire(
                 g, state.shifts, state.mean_shift, state.pod_shifts,
@@ -567,8 +585,8 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
         gnorm = jnp.sqrt(sum(
             jnp.sum(jnp.square(x.astype(jnp.float32)))
             for x in jax.tree.leaves(g)) / m)
-        extras = (_debug_extras(g, direction, new_shifts, new_ms)
-                  if debug_metrics else {})
+        extras = {**stats, **(_debug_extras(g, direction, new_shifts, new_ms)
+                              if debug_metrics else {})}
         return (direction, new_shifts, new_ms, new_psh, new_pms,
                 jnp.mean(losses), gnorm, extras)
 

@@ -225,6 +225,7 @@ def run_fleet(args, tr, b, callback=None):
             start=start_round)
 
         def log(t, state, metrics):
+            record_moe_rows(t, metrics)
             reporter.report(t, metrics, cohort=m)
             if callback is not None:
                 callback(t, state, metrics)
@@ -495,6 +496,19 @@ def record_wire_paths(agg, params) -> None:
                               elements=paths[f"{path}_elements"])
 
 
+def record_moe_rows(t: int, metrics: dict) -> None:
+    """Counters `moe.local_rows` (copies routed to the experts this chip
+    holds, summed over the layers and clients) and `moe.max_expert_rows`
+    (the most any held expert got in a layer) of round `t`; MoE
+    configurations only. The values stay on the device until the sink's
+    writer reads them."""
+    if "moe_local_rows" in metrics:
+        telemetry.counter("moe.local_rows", metrics["moe_local_rows"],
+                          round=t)
+        telemetry.counter("moe.max_expert_rows",
+                          metrics["moe_max_expert_rows"], round=t)
+
+
 def main(argv=None):
     ap = build_parser()
     args = ap.parse_args(argv)
@@ -603,6 +617,7 @@ def run(args, tr: Trainer, callback=None) -> steps.TrainState:
                     telemetry.counter("wire.uplink_bits",
                                       m * bits_per_client, round=t)
                     telemetry.round_metrics(t, metrics)
+                    record_moe_rows(t, metrics)
                 reporter.report(t, metrics)
                 if callback is not None:
                     callback(t, state, metrics)

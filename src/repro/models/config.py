@@ -24,13 +24,35 @@ class ArchConfig:
     qkv_bias: bool = False
     sliding_window: int | None = None
     mrope_sections: tuple[int, int, int] | None = None  # (t, h, w) — qwen2-vl
-    attention_mixer: str = "attn"  # 'attn' | 'rwkv6' | 'hymba'
+    attention_mixer: str = "attn"  # 'attn' | 'mla' | 'rwkv6' | 'hymba'
+    # latent attention (mla, DeepSeek-V2/V3 with q_lora_rank null): keys and
+    # values come from a normalised kv_lora_rank-wide latent; each head's
+    # query/key is qk_nope_head_dim wide plus a qk_rope_head_dim-wide
+    # rotated part (the key's shared by the heads), its value v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # ffn
     act: str = "swiglu"  # 'swiglu' | 'gelu'
     num_experts: int = 0
     experts_per_token: int = 0
     shared_expert_ff: int = 0  # qwen2-moe shared experts as one fused FFN
+    # the router's outputs; 0 = num_experts. Larger when this chip holds a
+    # share of the experts: num_experts is then how many it holds (the
+    # first num_experts of the router's)
+    router_experts: int = 0
+    # 'softmax': top-k of softmax probabilities, renormalised (qwen2-moe);
+    # 'sigmoid': DeepSeek-V3's noaux_tc — top-k of sigmoid scores plus a
+    # selection-only bias, weighted by the unbiased scores, renormalised,
+    # times routed_scaling
+    router: str = "softmax"
+    routed_scaling: float = 1.0
+    # leading dense SwiGLU layers of width dense_d_ff before the expert
+    # stack (DeepSeek's first_k_dense_replace); counted in num_layers
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0
 
     # ssm / hybrid
     ssm_state: int = 0
@@ -71,12 +93,23 @@ class ArchConfig:
         """Sub-quadratic decode path exists (DESIGN.md §Arch-applicability)."""
         return self.attention_mixer in ("rwkv6", "hymba") or self.sliding_window is not None
 
+    @property
+    def n_router(self) -> int:
+        """The router's outputs (the experts of the whole layer)."""
+        return self.router_experts or self.num_experts
+
     def param_count(self) -> int:
         """Approximate total parameters (embedding + blocks), for 6ND."""
         d, f, hd = self.d_model, self.d_ff, self.head_dim
         qh, kh = self.num_heads, self.num_kv_heads
         attn = d * qh * hd + 2 * d * kh * hd + qh * hd * d
-        if self.attention_mixer == "rwkv6":
+        if self.attention_mixer == "mla":
+            r, qk = self.kv_lora_rank, self.qk_nope_head_dim
+            attn = (d * qh * (qk + self.qk_rope_head_dim)
+                    + d * (r + self.qk_rope_head_dim) + r
+                    + r * qh * (qk + self.v_head_dim)
+                    + qh * self.v_head_dim * d)
+        elif self.attention_mixer == "rwkv6":
             # r,k,v,g,w projections + output
             attn = 6 * d * d
         elif self.attention_mixer == "hymba":
@@ -84,13 +117,18 @@ class ArchConfig:
             attn += 2 * d * ssm_inner + ssm_inner * d + ssm_inner * (2 * self.ssm_state + 2)
         if self.num_experts:
             ffn = self.num_experts * (3 if self.act == "swiglu" else 2) * d * f
-            ffn += d * self.num_experts
+            ffn += d * self.n_router
+            if self.router == "sigmoid":
+                ffn += self.n_router  # the selection bias
             if self.shared_expert_ff:
                 ffn += (3 if self.act == "swiglu" else 2) * d * self.shared_expert_ff
         else:
             ffn = (3 if self.act == "swiglu" else 2) * d * f
         per_layer = attn + ffn + 2 * d
         total = self.num_layers * per_layer + self.vocab * d
+        if self.first_dense_layers:
+            dense = attn + 3 * d * self.dense_d_ff + 2 * d
+            total += self.first_dense_layers * (dense - per_layer)
         if not self.tie_embeddings:
             total += self.vocab * d
         if self.is_encdec:
@@ -108,4 +146,5 @@ class ArchConfig:
         n_ff_mats = 3 if self.act == "swiglu" else 2
         dense_ffn = self.num_experts * n_ff_mats * d * f
         active_ffn = self.experts_per_token * n_ff_mats * d * f
-        return self.param_count() - self.num_layers * (dense_ffn - active_ffn)
+        moe_layers = self.num_layers - self.first_dense_layers
+        return self.param_count() - moe_layers * (dense_ffn - active_ffn)

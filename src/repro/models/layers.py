@@ -107,13 +107,16 @@ def _gqa_expand(k, n_rep: int):
 
 
 def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None,
-                      q_offset: int = 0, block: int = 1024):
+                      q_offset: int = 0, block: int = 1024,
+                      remat_blocks: bool = False):
     """Block-sparse streaming-softmax attention.
 
-    q: (B, Sq, H, hd); k, v: (B, Skv, KH, hd) with H % KH == 0.
+    q, k: (B, Sq|Skv, H|KH, hd); v: (B, Skv, KH, vd) with H % KH == 0;
+    the value width vd may differ from hd (latent attention), and the
+    scores are scaled by 1/sqrt(hd).
     q_offset: absolute position of q[0] relative to k[0] (decode/prefill).
     window: sliding-window size (None = full).
-    Returns (B, Sq, H, hd).
+    Returns (B, Sq, H, vd).
 
     Queries are processed in blocks too (§Perf change G): for each q block
     only the kv blocks that are not FULLY masked are visited — upper-triangle
@@ -121,9 +124,14 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None
     out-of-window blocks under SWA (Skv/window x, e.g. 16x for hymba's
     window-1024 at 4k context). Partially-masked diagonal blocks keep the
     exact elementwise mask, so results are identical to dense masking.
+
+    remat_blocks: recompute each kv block's scores in the backward pass
+    instead of keeping them: the backward then holds the running
+    statistics per block, not its (B, H, block, block) probabilities
+    (128 MiB a block at 16 heads, 36 blocks a layer at 8k context).
     """
     b, sq, h, hd = q.shape
-    skv, kh = k.shape[1], k.shape[2]
+    skv, kh, vd = k.shape[1], k.shape[2], v.shape[-1]
     k = _gqa_expand(k, h // kh)
     v = _gqa_expand(v, h // kh)
     scale = 1.0 / math.sqrt(hd)
@@ -135,7 +143,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kb = k.reshape(b, nblk, block, h, hd)
-    vb = v.reshape(b, nblk, block, h, hd)
+    vb = v.reshape(b, nblk, block, h, vd)
 
     qb_size = min(block, sq)
     nqb = (sq + qb_size - 1) // qb_size
@@ -174,7 +182,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None
             )
             return (m_new, l_new, acc), None
 
-        return body
+        return jax.checkpoint(body) if remat_blocks else body
 
     outs = []
     for qi in range(nqb):
@@ -187,7 +195,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None
         idx = jnp.arange(j_lo, j_hi + 1)
         m0 = jnp.full((b, h, qb_size), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((b, h, qb_size), jnp.float32)
-        acc0 = jnp.zeros((b, h, qb_size, hd), jnp.float32)
+        acc0 = jnp.zeros((b, h, qb_size, vd), jnp.float32)
         (m, l, acc), _ = lax.scan(
             make_body(q_blk, q_offset + qi * qb_size + jnp.arange(qb_size)),
             (m0, l0, acc0),
@@ -198,7 +206,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int | None = None
         outs.append(acc / jnp.maximum(l, 1e-30)[..., None])
     out = jnp.concatenate(outs, axis=2) if nqb > 1 else outs[0]
     out = out[:, :, :sq]
-    return jnp.moveaxis(out, 1, 2).astype(q.dtype)  # (B, Sq, H, hd)
+    return jnp.moveaxis(out, 1, 2).astype(q.dtype)  # (B, Sq, H, vd)
 
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, window: int | None = None):

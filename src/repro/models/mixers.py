@@ -1,4 +1,5 @@
-"""Sequence mixers: softmax attention (GQA/RoPE/M-RoPE/SWA), RWKV6, Hymba.
+"""Sequence mixers: softmax attention (GQA/RoPE/M-RoPE/SWA), latent
+attention (MLA), RWKV6, Hymba.
 
 Every mixer exposes the same three entry points so the block assembly in
 `transformer.py` stays family-agnostic:
@@ -26,6 +27,7 @@ from repro.models.layers import (
     chunked_attention,
     decode_attention,
     linear,
+    rmsnorm,
 )
 from repro.models.linear_attention import (
     chunked_linear_attention,
@@ -147,6 +149,115 @@ def attention_decode(p, x, cfg: ArchConfig, cache: AttnCache, pos,
     out = decode_attention(q, kc, vc, n_valid, window=None)
     y = linear(out.reshape(b, 1, -1), p["wo"])
     return y, AttnCache(kc, vc)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA: DeepSeek-V2/V3 with q_lora_rank null)
+# ---------------------------------------------------------------------------
+
+class MLACache(NamedTuple):
+    ckv: jax.Array  # (B, C, kv_lora_rank) normalised latent
+    kpe: jax.Array  # (B, C, qk_rope_head_dim) rotated key part, all heads'
+
+
+def init_mla(key, cfg: ArchConfig):
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": _normal(ks[0], (d, h * qk), cfg.dtype, d),
+        "wkv_a": _normal(ks[1], (d, r + cfg.qk_rope_head_dim), cfg.dtype, d),
+        "kv_norm": {"scale": jnp.ones((r,), cfg.dtype)},
+        "wkv_b": _normal(ks[2], (r, h * (cfg.qk_nope_head_dim
+                                         + cfg.v_head_dim)), cfg.dtype, r),
+        "wo": _normal(ks[3], (h * cfg.v_head_dim, d), cfg.dtype,
+                      h * cfg.v_head_dim),
+    }
+
+
+def _mla_queries(p, x, cfg: ArchConfig, positions):
+    """(B, S, H, nope + rope): each head's query, its rope part rotated."""
+    b, s, _ = x.shape
+    nope = cfg.qk_nope_head_dim
+    q = linear(x, p["wq"]).reshape(b, s, cfg.num_heads, -1)
+    q_pe = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+    return jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+
+
+def _mla_latent(p, x, cfg: ArchConfig, positions):
+    """What the cache holds per position: the normalised latent (B, S, r)
+    and the rotated key part shared by the heads (B, S, rope)."""
+    kv = linear(x, p["wkv_a"])
+    ckv = rmsnorm(kv[..., :cfg.kv_lora_rank], p["kv_norm"]["scale"])
+    kpe = apply_rope(kv[..., None, cfg.kv_lora_rank:], positions,
+                     cfg.rope_theta)
+    return ckv, kpe[:, :, 0]
+
+
+def _mla_attend(p, q, ckv, kpe, cfg: ArchConfig):
+    """Causal attention of q over keys and values expanded from the
+    latent; scores at 1/sqrt(nope + rope), values v_head_dim wide. The
+    kv blocks' scores are recomputed in the backward pass: keeping them,
+    Moonlight's chip-share step at 2 x 8192 tokens needs 18.73 GiB of a
+    TPU v5e's 15.75 and does not compile (8.70 GiB of temporaries with
+    the recompute)."""
+    b, s, h, _ = q.shape
+    nope = cfg.qk_nope_head_dim
+    kv = linear(ckv, p["wkv_b"]).reshape(b, s, h, -1)
+    k_pe = jnp.broadcast_to(kpe[:, :, None], (b, s, h, kpe.shape[-1]))
+    k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+    out = chunked_attention(q, k, kv[..., nope:], causal=True,
+                            remat_blocks=True)
+    return linear(out.reshape(b, s, -1), p["wo"])
+
+
+def mla_train(p, x, cfg: ArchConfig, *, positions):
+    with jax.named_scope("mla"):
+        q = _mla_queries(p, x, cfg, positions)
+        ckv, kpe = _mla_latent(p, x, cfg, positions)
+        return _mla_attend(p, q, ckv, kpe, cfg)
+
+
+def mla_prefill(p, x, cfg: ArchConfig, *, positions, cache_len: int):
+    """Attention over the prompt; the cache keeps the latent and the
+    rotated key part of its positions (capacity `cache_len`)."""
+    b, s, _ = x.shape
+    with jax.named_scope("mla"):
+        q = _mla_queries(p, x, cfg, positions)
+        ckv, kpe = _mla_latent(p, x, cfg, positions)
+        y = _mla_attend(p, q, ckv, kpe, cfg)
+    cache = MLACache(
+        jnp.zeros((b, cache_len, cfg.kv_lora_rank), x.dtype).at[:, :s].set(
+            ckv),
+        jnp.zeros((b, cache_len, cfg.qk_rope_head_dim), x.dtype).at[
+            :, :s].set(kpe))
+    return y, cache
+
+
+def mla_decode(p, x, cfg: ArchConfig, cache: MLACache, pos):
+    """x: (B, 1, D). Attention straight over the latent cache: the key
+    expansion is absorbed into the query and the value expansion applied
+    after the weighted sum, so no per-position key or value is built."""
+    b = x.shape[0]
+    h, nope, r = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    pos_b = jnp.broadcast_to(pos, (b, 1))
+    with jax.named_scope("mla"):
+        q = _mla_queries(p, x, cfg, pos_b)[:, 0].astype(jnp.float32)
+        ckv, kpe = _mla_latent(p, x, cfg, pos_b)
+        ckv_c = lax.dynamic_update_slice(cache.ckv, ckv, (0, pos, 0))
+        kpe_c = lax.dynamic_update_slice(cache.kpe, kpe, (0, pos, 0))
+        w = p["wkv_b"].astype(jnp.float32).reshape(r, h, -1)
+        c32, pe32 = ckv_c.astype(jnp.float32), kpe_c.astype(jnp.float32)
+        q_lat = jnp.einsum("bhn,rhn->bhr", q[..., :nope], w[..., :nope])
+        s = (jnp.einsum("bhr,bcr->bhc", q_lat, c32)
+             + jnp.einsum("bhp,bcp->bhc", q[..., nope:], pe32))
+        s = s / math.sqrt(q.shape[-1])
+        valid = jnp.arange(c32.shape[1])[None, None] <= pos
+        probs = jax.nn.softmax(jnp.where(valid, s, -jnp.inf), axis=-1)
+        o_lat = jnp.einsum("bhc,bcr->bhr", probs, c32)
+        out = jnp.einsum("bhr,rhv->bhv", o_lat, w[..., nope:])
+        y = linear(out.reshape(b, 1, -1).astype(x.dtype), p["wo"])
+    return y, MLACache(ckv_c, kpe_c)
 
 
 # ---------------------------------------------------------------------------

@@ -1,47 +1,78 @@
 """Mixture-of-Experts FFN — capacity-free (dropless) top-k routing.
 
 TPU-native dispatch (DESIGN.md §5): tokens stay data-sharded, every expert's
-d_ff is tensor-parallel over the "model" axis, and dispatch is a *per-batch-row
-local sort* + `lax.ragged_dot_general`:
+d_ff is tensor-parallel over the "model" axis, and dispatch is a local sort
+of the client's token copies + grouped matmuls:
 
-  1. router logits -> top-k experts + softmax weights per token
-  2. per batch row, replicate tokens k times and argsort by expert id
-     (a local sort: the sorted axis is never sharded, so no collectives)
-  3. one batched ragged_dot per FFN matmul — only active-expert FLOPs
+  1. router logits -> top-k experts + their weights per token
+  2. replicate the tokens k times and argsort the copies by expert id (a
+     local sort: the batch of one client lives on its chips)
+  3. one grouped matmul per FFN matrix — only the routed rows' FLOPs
   4. unsort, weighted-sum over the k copies
 
-Qwen2-MoE's 4 shared experts are folded into one dense FFN of width
-`shared_expert_ff` applied to every token (mathematically identical to always-
-routed experts of the same total width).
+Routers (`ArchConfig.router`): "softmax" takes the top k of the softmax
+probabilities and renormalises them (qwen2-moe, dbrx). "sigmoid" is
+DeepSeek-V3's `noaux_tc` with one group: sigmoid scores over every expert,
+the top k chosen by score plus a correction bias that only selects (it
+never weights, and no gradient reaches it), the chosen scores renormalised
+and times `routed_scaling`.
+
+A layer may hold a share of the experts (model-configs guide §4, expert
+parallelism without its exchange): `num_experts` held of the router's
+`n_router` outputs, starting at `first_expert`. It routes over all of
+them and computes only its own experts' part of the result, for the
+copies routed to them; what the absent experts would add is left out.
+The copies are sorted with the held experts first, so they are groups
+0..num_experts-1 and every other copy lies past the last group.
+
+The grouped matmuls are `lax.ragged_dot_general` off the TPU. On the TPU
+they are megablox's Pallas kernels, `gmm` forward and for the input
+cotangent and `tgmm` for the weight cotangent, under the jit names
+`moe_gmm` and `moe_tgmm` so a device trace can find them; a trailing
+group of the copies of absent experts is never computed (only the held
+experts' tiles run) and comes back zero. GSPMD cannot split them: under
+a mesh of several chips each chip runs its own clients' products (a
+shard_map over the client axes), and a mesh that splits the experts'
+widths over "model" is refused.
+
+Qwen2-MoE's 4 shared experts (DeepSeek's 2) are folded into one dense FFN
+of width `shared_expert_ff` applied to every token (mathematically
+identical to always-routed experts of the same total width).
 
 Note (roofline): on the CPU backend XLA lowers ragged_dot as a dense
-group-loop, so `cost_analysis()` FLOPs over-count by ~E/k; on TPU the
-Megablox/grouped-matmul lowering does active FLOPs only. Recorded in
-EXPERIMENTS.md §Roofline via the MODEL_FLOPS/HLO_FLOPS ratio.
+group-loop, so `cost_analysis()` FLOPs over-count by ~E/k.
 """
 from __future__ import annotations
 
+import functools
+import importlib
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import custom_batching, lax
 from jax.lax import RaggedDotDimensionNumbers, ragged_dot_general
+from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ArchConfig
 from repro.models.layers import init_mlp, linear, mlp
 
 
 def init_moe(key, cfg: ArchConfig):
+    """Every leaf in the configuration's dtype, the router's too."""
     d, f, e = cfg.d_model, cfg.d_ff, cfg.num_experts
     ks = jax.random.split(key, 5)
     s_in, s_out = 1.0 / math.sqrt(d), 1.0 / math.sqrt(f)
     p = {
-        "router": jax.random.normal(ks[0], (d, e), jnp.float32) * s_in,
+        "router": jax.random.normal(ks[0], (d, cfg.n_router), cfg.dtype)
+        * s_in,
         "w_gate": jax.random.normal(ks[1], (e, d, f), cfg.dtype) * s_in,
         "w_up": jax.random.normal(ks[2], (e, d, f), cfg.dtype) * s_in,
         "w_down": jax.random.normal(ks[3], (e, f, d), cfg.dtype) * s_out,
     }
+    if cfg.router == "sigmoid":
+        p["b_router"] = jnp.zeros((cfg.n_router,), cfg.dtype)
     if cfg.shared_expert_ff:
         p["shared"] = init_mlp(ks[4], d, cfg.shared_expert_ff, cfg.act, cfg.dtype)
     return p
@@ -144,62 +175,233 @@ def _ragged_bwd(res, g):
 _ragged.defvjp(_ragged_fwd, _ragged_bwd)
 
 
-def moe_ffn(p, x, cfg: ArchConfig, *, return_aux: bool = False):
-    """x: (B, S, D) -> (B, S, D). Works for S == 1 (decode) unchanged."""
+# ---------------------------------------------------------------------------
+# the TPU's grouped matmuls (megablox, Pallas)
+# ---------------------------------------------------------------------------
+
+_MEGABLOX = "jax.experimental.pallas.ops.tpu.megablox.gmm"
+
+
+def _tile(dim: int, want: int) -> int:
+    """`want` where it divides `dim`, else the whole dimension."""
+    return want if dim % want == 0 else dim
+
+
+def _tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """(rows, contraction, output) tiles: 512 rows where the rows allow
+    (a multiple of 128 otherwise), 512-wide contraction and output tiles
+    where they divide (whole up to 1536 wide otherwise: 1408 = 11 * 128).
+    At the chip share's widths every kernel's buffers fit the 16 MiB of
+    scoped VMEM of a v5e core."""
+    tm = 512 if m % 512 == 0 else 128
+    return tm, _tile(k, 512), n if n <= 1536 else _tile(n, 512)
+
+
+@partial(jax.jit, static_argnames=("transpose_rhs", "interpret"))
+def moe_gmm(lhs, rhs, group_sizes, transpose_rhs: bool = False,
+            interpret: bool = False):
+    """(M, K) rows in groups x (G, K, N) -> (M, N): each of the first G
+    groups of `group_sizes` times its matrix; rows of later groups come
+    back zero and are never computed."""
+    gmm = importlib.import_module(_MEGABLOX).gmm.__wrapped__
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    return gmm(lhs, rhs, group_sizes, lhs.dtype,
+               _tiling(lhs.shape[0], lhs.shape[1], n),
+               transpose_rhs=transpose_rhs, interpret=interpret)
+
+
+@partial(jax.jit, static_argnames=("num_groups", "interpret"))
+def moe_tgmm(lhs_t, g, group_sizes, num_groups: int, interpret: bool = False):
+    """(K, M) x (M, N) -> (num_groups, K, N): per group among the first
+    `num_groups`, the sum over its rows of lhs^T g."""
+    tgmm = importlib.import_module(_MEGABLOX).tgmm.__wrapped__
+    return tgmm(lhs_t, g, group_sizes, g.dtype,
+                _tiling(g.shape[0], lhs_t.shape[0], g.shape[1]),
+                num_actual_groups=num_groups, interpret=interpret)
+
+
+def _per_member(f):
+    """A custom_vmap rule that runs `f` once per batch member, the batch
+    being the train step's clients. Under a mesh of several chips each
+    chip runs its own clients (a shard_map over the client axes: GSPMD
+    cannot split a Pallas kernel); one chip holds one client, so the loop
+    runs once."""
+    def loop(*args):
+        if args[0].shape[0] == 1:
+            return f(*(a[0] for a in args))[None]
+        return lax.map(lambda a: f(*a), args)
+
+    def rule(axis_size, in_batched, *args):
+        args = _batch_all(axis_size, in_batched, *args)
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty or mesh.size == 1:
+            return loop(*args), True
+        clients = P(tuple(a for a in mesh.axis_names if a != "model"))
+        return jax.shard_map(loop, mesh=mesh, in_specs=clients,
+                             out_specs=clients, check_vma=False)(*args), True
+    return rule
+
+
+def _with_rest(gs, m):
+    """The held experts' group sizes, then one group of the other rows."""
+    return jnp.concatenate([gs, (m - jnp.sum(gs))[None]]).astype(jnp.int32)
+
+
+def _vmapped(f):
+    op = custom_batching.custom_vmap(f)
+    op.def_vmap(_per_member(f))
+    return op
+
+
+@functools.cache
+def _kernel_grouped(interpret: bool = False):
+    """The Pallas grouped matmul with its backward pass, on (M, K) rows
+    sorted by group and the held experts' group sizes."""
+    fwd = _vmapped(lambda lhs, rhs, gs: moe_gmm(
+        lhs, rhs, _with_rest(gs, lhs.shape[0]), interpret=interpret))
+    dlhs = _vmapped(lambda g, rhs, gs: moe_gmm(
+        g, rhs, _with_rest(gs, g.shape[0]), transpose_rhs=True,
+        interpret=interpret))
+    drhs = _vmapped(lambda lhs, g, gs: moe_tgmm(
+        lhs.T, g, _with_rest(gs, g.shape[0]), gs.shape[-1],
+        interpret=interpret))
+
+    @jax.custom_vjp
+    def grouped(lhs, rhs, gs):
+        return fwd(lhs, rhs, gs)
+
+    def grouped_fwd(lhs, rhs, gs):
+        return fwd(lhs, rhs, gs), (lhs, rhs, gs)
+
+    def grouped_bwd(res, g):
+        lhs, rhs, gs = res
+        g = g.astype(lhs.dtype)
+        return dlhs(g, rhs, gs), drhs(lhs, g, gs).astype(rhs.dtype), None
+
+    grouped.defvjp(grouped_fwd, grouped_bwd)
+    return grouped
+
+
+def _ragged_grouped(lhs, rhs, gs):
+    """The same product off the TPU, as ragged_dot: the rows past the
+    held groups form one more group, of a zero matrix (so that every row
+    lies in a group when the client axis folds into the groups)."""
+    rest = jnp.zeros((1,) + rhs.shape[1:], rhs.dtype)
+    sizes = _with_rest(gs, lhs.shape[0])
+    return _ragged(lhs[None], jnp.concatenate([rhs, rest]), sizes[None])[0]
+
+
+def _whole_experts() -> bool:
+    """No "model" axis of the traced program's mesh splits the experts'
+    widths (DESIGN.md §5 shards their d_ff over it)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh.empty or dict(mesh.shape).get("model", 1) == 1
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, kernel: bool | None = None,
+                   interpret: bool = False):
+    """lhs (M, K) rows sorted by group, rhs (G, K, N), group_sizes (G,) ->
+    (M, N); rows past the G groups come back zero. `kernel` (default: on
+    a TPU, for whole 128-row tiles) picks the Pallas kernels over
+    ragged_dot. The kernels need each chip to hold its experts whole."""
+    if kernel is None:
+        kernel = jax.default_backend() == "tpu" and lhs.shape[0] % 128 == 0
+    if kernel:
+        if not _whole_experts():
+            raise NotImplementedError(
+                "the expert layer's Pallas kernels need whole experts on "
+                "every chip; this mesh splits their d_ff over 'model' "
+                "(DESIGN.md §5)")
+        return _kernel_grouped(interpret)(lhs, rhs, group_sizes)
+    return _ragged_grouped(lhs, rhs, group_sizes)
+
+
+# ---------------------------------------------------------------------------
+# routing and the layer
+# ---------------------------------------------------------------------------
+
+def route(p, x, cfg: ArchConfig):
+    """x (..., D) -> (weights (..., k) f32, experts (..., k) int32, the
+    scores over every expert (..., n_router) f32)."""
+    k = cfg.experts_per_token
+    logits = linear(x.astype(jnp.float32), p["router"].astype(jnp.float32))
+    if cfg.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        bias = lax.stop_gradient(p["b_router"].astype(jnp.float32))
+        _, top_e = lax.top_k(scores + bias, k)
+        top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+        top_w = top_w / (jnp.sum(top_w, -1, keepdims=True) + 1e-20)
+        return top_w * cfg.routed_scaling, top_e, scores
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_e = lax.top_k(probs, k)
+    top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    return top_w, top_e, probs
+
+
+def moe_routed(p, x, cfg: ArchConfig, *, first_expert: int = 0):
+    """The held experts' part of the routed result: x (B, S, D) -> (y
+    (B, S, D), stats). The layer holds experts first_expert ..
+    first_expert + num_experts - 1 of the router's n_router. stats:
+    `local_rows`, the copies routed to the held experts, and
+    `max_expert_rows`, the most any one of them got."""
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.experts_per_token
-    logits = linear(x.astype(jnp.float32), p["router"])  # (B, S, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = lax.top_k(probs, k)  # (B, S, K)
-    top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    with jax.named_scope("moe_router"):
+        top_w, top_e, _ = route(p, x, cfg)
+    with jax.named_scope("moe_dispatch"):
+        # held experts first: copy ids relative to the first held expert
+        flat_e = (top_e.reshape(b * s * k) - first_expert) % cfg.n_router
+        order = jnp.argsort(flat_e)
+        inv = jnp.argsort(order)
+        xs = jnp.take(x.reshape(b * s, d), order // k, axis=0)
+        counts = jnp.sum(jax.nn.one_hot(flat_e, e, dtype=jnp.int32), axis=0)
+    with jax.named_scope("moe_experts"):
+        mm = partial(grouped_matmul, group_sizes=counts)
+        if cfg.act == "swiglu":
+            h = jax.nn.silu(mm(xs, p["w_gate"])) * mm(xs, p["w_up"])
+        else:
+            h = jax.nn.gelu(mm(xs, p["w_up"]))
+        ys = mm(h, p["w_down"])
+    with jax.named_scope("moe_dispatch"):
+        yk = jnp.take(ys, inv, axis=0).reshape(b, s, k, d)
+        y = jnp.sum(yk * top_w[..., None].astype(yk.dtype), axis=2)
+    return y, {"local_rows": jnp.sum(counts),
+               "max_expert_rows": jnp.max(counts)}
 
-    # flatten token copies per row: (B, S*K)
-    flat_e = top_e.reshape(b, s * k)
-    order = jnp.argsort(flat_e, axis=-1)  # local sort per batch row
-    inv = jnp.argsort(order, axis=-1)
-    xk = jnp.repeat(x, k, axis=1)  # (B, S*K, D) token copies
-    xs = jnp.take_along_axis(xk, order[..., None], axis=1)
-    counts = jnp.sum(
-        jax.nn.one_hot(flat_e, e, dtype=jnp.int32), axis=1
-    )  # (B, E) group sizes
 
-    if cfg.act == "swiglu":
-        h = jax.nn.silu(_ragged(xs, p["w_gate"], counts)) * _ragged(
-            xs, p["w_up"], counts
-        )
-    else:
-        h = jax.nn.gelu(_ragged(xs, p["w_up"], counts))
-    ys = _ragged(h, p["w_down"], counts)  # (B, S*K, D)
-
-    yk = jnp.take_along_axis(ys, inv[..., None], axis=1).reshape(b, s, k, d)
-    y = jnp.sum(yk * top_w[..., None].astype(yk.dtype), axis=2)
-
+def moe_ffn(p, x, cfg: ArchConfig, *, return_aux: bool = False,
+            with_stats: bool = False):
+    """x: (B, S, D) -> (B, S, D): the held experts' part plus the shared
+    experts. Works for S == 1 (decode) unchanged. `with_stats` returns
+    (y, stats) (`moe_routed`)."""
+    y, stats = moe_routed(p, x, cfg)
     if cfg.shared_expert_ff:
-        y = y + mlp(x, p["shared"], cfg.act)
+        with jax.named_scope("moe_shared"):
+            y = y + mlp(x, p["shared"], cfg.act)
 
     if return_aux:
         # Switch-style load-balance diagnostics (fraction routed per expert
         # vs mean router prob) — exposed to the training loop for logging.
+        _, top_e, probs = route(p, x, cfg)
         frac = jnp.mean(
-            jax.nn.one_hot(top_e, e, dtype=jnp.float32), axis=(0, 1, 2)
-        )
+            jax.nn.one_hot(top_e, cfg.n_router, dtype=jnp.float32),
+            axis=(0, 1, 2))
         mean_p = jnp.mean(probs, axis=(0, 1))
-        aux = e * jnp.sum(frac * mean_p)
+        aux = cfg.n_router * jnp.sum(frac * mean_p)
         return y, aux
+    if with_stats:
+        return y, stats
     return y
 
 
 def moe_ffn_ref(p, x, cfg: ArchConfig):
-    """Dense-einsum oracle (all experts for all tokens, masked sum) — used by
-    tests to validate the ragged dispatch."""
-    e, k = cfg.num_experts, cfg.experts_per_token
-    logits = linear(x.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = lax.top_k(probs, k)
-    top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
-    # (B, S, E) combine weights
-    comb = jnp.zeros(probs.shape, jnp.float32)
-    comb = jnp.sum(jax.nn.one_hot(top_e, e) * top_w[..., None], axis=2)
+    """Dense-einsum oracle (every held expert for every token, masked
+    sum) — used by tests to validate the grouped dispatch."""
+    e = cfg.num_experts
+    top_w, top_e, _ = route(p, x, cfg)
+    # (B, S, E) combine weights of the held experts
+    comb = jnp.sum(jax.nn.one_hot(top_e, cfg.n_router)[..., :e]
+                   * top_w[..., None], axis=2)
     if cfg.act == "swiglu":
         h = jax.nn.silu(jnp.einsum("bsd,edf->bsef", x, p["w_gate"])) * jnp.einsum(
             "bsd,edf->bsef", x, p["w_up"]

@@ -3,7 +3,8 @@
 One assembly covers all six assigned families (DESIGN.md §4):
 
   dense  — GQA + RoPE (+ optional sliding window / QKV bias)
-  moe    — dense attention + capacity-free top-k MoE FFN (`moe.py`)
+  moe    — dense or latent attention + capacity-free top-k MoE FFN
+           (`moe.py`), optionally after leading dense layers
   ssm    — RWKV6 mixer, attention-free (`mixers.py`)
   hybrid — Hymba parallel attention+SSD heads
   vlm    — qwen2-vl: M-RoPE, patch-embedding stub spliced into the stream
@@ -12,19 +13,23 @@ One assembly covers all six assigned families (DESIGN.md §4):
 
 Layer parameters are *stacked* (leading L axis) and the stack is traversed
 with `lax.scan`, keeping compile time flat in depth (deepseek-67b has 95
-layers). Entry points:
+layers). Leading dense layers (`first_dense_layers`, DeepSeek's
+first_k_dense_replace) are a stack of their own, `dense_blocks`, scanned
+before `blocks`; their caches sit under the same two names. Entry points:
 
   init_params(key, cfg)                         -> params
   loss_fn(params, batch, cfg)                   -> scalar loss
+                                                   (, MoE stats)
   forward(params, batch, cfg)                   -> logits          (no loss)
   prefill(params, batch, cfg, cache_len)        -> (last logits, cache)
   decode_step(params, cache, tokens, pos, cfg)  -> (logits, cache)
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from functools import partial
-from typing import Any, NamedTuple
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -48,12 +53,30 @@ from repro.models.moe import init_moe, moe_ffn
 # init
 # ---------------------------------------------------------------------------
 
+def _dense_config(cfg: ArchConfig) -> ArchConfig:
+    """The leading dense layers' config: an MLP of width dense_d_ff."""
+    return dataclasses.replace(cfg, num_experts=0, router_experts=0,
+                               d_ff=cfg.dense_d_ff, first_dense_layers=0,
+                               dense_d_ff=0)
+
+
+def _stacks(params, cfg: ArchConfig):
+    """(name, stacked params, config) of each layer stack, in order."""
+    out = []
+    if cfg.first_dense_layers:
+        out.append(("dense_blocks", params["dense_blocks"],
+                    _dense_config(cfg)))
+    return out + [("blocks", params["blocks"], cfg)]
+
+
 def _init_block(key, cfg: ArchConfig):
     ks = jax.random.split(key, 6)
     p = {"ln1": init_norm(cfg.d_model, cfg.norm, cfg.dtype),
          "ln2": init_norm(cfg.d_model, cfg.norm, cfg.dtype)}
     if cfg.attention_mixer == "attn":
         p["mixer"] = mixers.init_attention(ks[0], cfg)
+    elif cfg.attention_mixer == "mla":
+        p["mixer"] = mixers.init_mla(ks[0], cfg)
     elif cfg.attention_mixer == "rwkv6":
         p["mixer"] = mixers.init_rwkv6(ks[0], cfg)
     elif cfg.attention_mixer == "hymba":
@@ -86,10 +109,14 @@ def init_params(key, cfg: ArchConfig):
     p: dict[str, Any] = {
         "embed": jax.random.normal(ks[0], (vp, cfg.d_model), cfg.dtype) * 0.02,
         "blocks": jax.vmap(lambda k: _init_block(k, cfg))(
-            jax.random.split(ks[1], cfg.num_layers)
+            jax.random.split(ks[1], cfg.num_layers - cfg.first_dense_layers)
         ),
         "final_norm": init_norm(cfg.d_model, cfg.norm, cfg.dtype),
     }
+    if cfg.first_dense_layers:
+        p["dense_blocks"] = jax.vmap(
+            lambda k: _init_block(k, _dense_config(cfg)))(
+                jax.random.split(ks[5], cfg.first_dense_layers))
     if not cfg.tie_embeddings:
         p["lm_head"] = jax.random.normal(ks[2], (vp, cfg.d_model), cfg.dtype) * 0.02
     if cfg.is_encdec:
@@ -151,15 +178,19 @@ def _sinusoid(s: int, d: int, dtype):
 # ---------------------------------------------------------------------------
 
 def _ffn(bp, x, cfg: ArchConfig):
+    """(y, stats): MoE routing stats (`moe.moe_routed`), {} for an MLP."""
     if cfg.num_experts:
-        return moe_ffn(bp["ffn"], x, cfg)
-    return mlp(x, bp["ffn"], cfg.act)
+        return moe_ffn(bp["ffn"], x, cfg, with_stats=True)
+    return mlp(x, bp["ffn"], cfg.act), {}
 
 
 def _block_train(bp, x, cfg: ArchConfig, positions, enc):
+    """-> (x, the FFN's stats)."""
     h = norm(x, bp["ln1"], cfg.norm)
     if cfg.attention_mixer == "attn":
         y = mixers.attention_train(bp["mixer"], h, cfg, positions=positions)
+    elif cfg.attention_mixer == "mla":
+        y = mixers.mla_train(bp["mixer"], h, cfg, positions=positions)
     elif cfg.attention_mixer == "rwkv6":
         y = mixers.rwkv6_train(bp["mixer"], h, cfg)
     else:
@@ -169,7 +200,8 @@ def _block_train(bp, x, cfg: ArchConfig, positions, enc):
         x = x + mixers.cross_attention_train(
             bp["cross"], norm(x, bp["ln_cross"], cfg.norm), enc, cfg
         )
-    return x + _ffn(bp, norm(x, bp["ln2"], cfg.norm), cfg)
+    y, stats = _ffn(bp, norm(x, bp["ln2"], cfg.norm), cfg)
+    return x + y, stats
 
 
 def _block_prefill(bp, x, cfg: ArchConfig, positions, enc, cache_len: int):
@@ -178,6 +210,9 @@ def _block_prefill(bp, x, cfg: ArchConfig, positions, enc, cache_len: int):
         y, c = mixers.attention_prefill(
             bp["mixer"], h, cfg, positions=positions, cache_len=cache_len
         )
+    elif cfg.attention_mixer == "mla":
+        y, c = mixers.mla_prefill(bp["mixer"], h, cfg, positions=positions,
+                                  cache_len=cache_len)
     elif cfg.attention_mixer == "rwkv6":
         y, c = mixers.rwkv6_prefill(bp["mixer"], h, cfg)
     else:
@@ -190,7 +225,7 @@ def _block_prefill(bp, x, cfg: ArchConfig, positions, enc, cache_len: int):
         hc = norm(x, bp["ln_cross"], cfg.norm)
         x = x + mixers.cross_attention_train(bp["cross"], hc, enc, cfg)
         cache["cross"] = mixers.cross_attention_cache(bp["cross"], enc, cfg)
-    return x + _ffn(bp, norm(x, bp["ln2"], cfg.norm), cfg), cache
+    return x + _ffn(bp, norm(x, bp["ln2"], cfg.norm), cfg)[0], cache
 
 
 def _block_decode(bp, x, cfg: ArchConfig, cache, pos, rope_pos):
@@ -199,6 +234,8 @@ def _block_decode(bp, x, cfg: ArchConfig, cache, pos, rope_pos):
         y, c = mixers.attention_decode(
             bp["mixer"], h, cfg, cache["mixer"], pos, rope_positions=rope_pos
         )
+    elif cfg.attention_mixer == "mla":
+        y, c = mixers.mla_decode(bp["mixer"], h, cfg, cache["mixer"], pos)
     elif cfg.attention_mixer == "rwkv6":
         y, c = mixers.rwkv6_decode(bp["mixer"], h, cfg, cache["mixer"])
     else:
@@ -209,7 +246,7 @@ def _block_decode(bp, x, cfg: ArchConfig, cache, pos, rope_pos):
         hc = norm(x, bp["ln_cross"], cfg.norm)
         x = x + mixers.cross_attention_decode(bp["cross"], hc, cfg, cache["cross"])
         new_cache["cross"] = cache["cross"]
-    return x + _ffn(bp, norm(x, bp["ln2"], cfg.norm), cfg), new_cache
+    return x + _ffn(bp, norm(x, bp["ln2"], cfg.norm), cfg)[0], new_cache
 
 
 def _apply_remat(body, remat):
@@ -227,7 +264,8 @@ def _apply_remat(body, remat):
 
 
 def _scan_blocks(blocks, x, body, *, remat, unroll: bool = False):
-    """Traverse the stacked layer params.
+    """Traverse the stacked layer params; body(bp, x) -> (x, aux), and the
+    layers' aux come back stacked: -> (x, aux).
 
     unroll=False: `lax.scan` — flat compile time (the production default).
     unroll=True: python loop — exact per-layer HLO, used by the dry-run so
@@ -239,15 +277,13 @@ def _scan_blocks(blocks, x, body, *, remat, unroll: bool = False):
 
     if unroll:
         n = jax.tree.leaves(blocks)[0].shape[0]
+        auxs = []
         for i in range(n):
-            x = body(jax.tree.map(lambda a: a[i], blocks), x)
-        return x
+            x, aux = body(jax.tree.map(lambda a: a[i], blocks), x)
+            auxs.append(aux)
+        return x, jax.tree.map(lambda *a: jnp.stack(a), *auxs)
 
-    def step(carry, bp):
-        return body(bp, carry), None
-
-    out, _ = lax.scan(step, x, blocks)
-    return out
+    return lax.scan(lambda carry, bp: body(bp, carry), x, blocks)
 
 
 # ---------------------------------------------------------------------------
@@ -266,9 +302,10 @@ def encode(params, frames, cfg: ArchConfig, *, remat="full",
             causal=False, window=None,
         )
         x = x + y
-        return x + mlp(norm(x, bp["ln2"], cfg.norm), bp["ffn"], cfg.act)
+        return x + mlp(norm(x, bp["ln2"], cfg.norm), bp["ffn"], cfg.act), {}
 
-    x = _scan_blocks(params["enc_blocks"], x, body, remat=remat, unroll=unroll)
+    x, _ = _scan_blocks(params["enc_blocks"], x, body, remat=remat,
+                        unroll=unroll)
     return norm(x, params["enc_final_norm"], cfg.norm)
 
 
@@ -323,10 +360,10 @@ def _streaming_ce(logits, labels, true_vocab: int):
 # public entry points
 # ---------------------------------------------------------------------------
 
-def forward(params, batch, cfg: ArchConfig, *, remat="full",
-            unroll: bool = False, head: str = "masked",
-            seq_shard: bool = False):
-    """Teacher-forced logits over the full input sequence."""
+def _hidden(params, batch, cfg: ArchConfig, *, remat, unroll: bool,
+            seq_shard: bool):
+    """The layers' output over the inputs, and the MoE stats summed (rows)
+    or maxed (per expert) over the layers ({} without experts)."""
     inputs = batch["tokens"][:, :-1] if batch["tokens"].shape[1] > 1 else batch["tokens"]
     b, s = inputs.shape
     enc = None
@@ -334,19 +371,41 @@ def forward(params, batch, cfg: ArchConfig, *, remat="full",
         enc = encode(params, batch["frames"], cfg, remat=remat, unroll=unroll)
     x = _embed_inputs(params, batch, cfg, inputs)
     positions = _positions(cfg, b, s)
-    body = partial(
-        lambda bp, x: _block_train(bp, x, cfg, positions, enc)
-    )
-    if seq_shard:
-        # §Perf change E — sequence parallelism: the per-layer residual
-        # (what jax.checkpoint stores for the backward pass) is sharded
-        # seq->"model", cutting the dominant activation-stash term 16x.
-        # GSPMD re-gathers inside the block where attention needs full seq.
-        from jax.sharding import PartitionSpec as _P
-        inner = body
-        body = lambda bp, x: inner(
-            bp, lax.with_sharding_constraint(x, _P(None, "model", None)))
-    x = _scan_blocks(params["blocks"], x, body, remat=remat, unroll=unroll)
+    stats = {}
+    for name, blocks, c in _stacks(params, cfg):
+        body = partial(
+            lambda bp, x, c: _block_train(bp, x, c, positions, enc), c=c
+        )
+        if seq_shard:
+            # §Perf change E — sequence parallelism: the per-layer residual
+            # (what jax.checkpoint stores for the backward pass) is sharded
+            # seq->"model", cutting the dominant activation-stash term 16x.
+            # GSPMD re-gathers inside the block where attention needs full
+            # seq.
+            from jax.sharding import PartitionSpec as _P
+            body = partial(lambda bp, x, inner: inner(
+                bp, lax.with_sharding_constraint(x, _P(None, "model", None))),
+                inner=body)
+        if name == "dense_blocks":
+            with jax.named_scope("dense_layers"):
+                x, _ = _scan_blocks(blocks, x, body, remat=remat,
+                                    unroll=unroll)
+        else:
+            x, aux = _scan_blocks(blocks, x, body, remat=remat,
+                                  unroll=unroll)
+            if aux:
+                stats = {"moe_local_rows": jnp.sum(aux["local_rows"]),
+                         "moe_max_expert_rows": jnp.max(
+                             aux["max_expert_rows"])}
+    return x, stats
+
+
+def forward(params, batch, cfg: ArchConfig, *, remat="full",
+            unroll: bool = False, head: str = "masked",
+            seq_shard: bool = False):
+    """Teacher-forced logits over the full input sequence."""
+    x, _ = _hidden(params, batch, cfg, remat=remat, unroll=unroll,
+                   seq_shard=seq_shard)
     if head == "raw":
         return _head_raw(params, x, cfg)
     return _head(params, x, cfg)
@@ -354,28 +413,55 @@ def forward(params, batch, cfg: ArchConfig, *, remat="full",
 
 def loss_fn(params, batch, cfg: ArchConfig, *, remat="full",
             unroll: bool = False, ce: str = "gather",
-            seq_shard: bool = False):
+            seq_shard: bool = False, with_stats: bool = False):
+    """Mean next-token loss; with `with_stats`, (loss, the MoE stats of
+    `_hidden`)."""
     labels = batch["tokens"][:, 1:]
     mask = None
     if cfg.family == "vlm" and "patches" in batch:
         # only text positions contribute to the LM loss
         p = batch["patches"].shape[1]
         mask = (jnp.arange(labels.shape[1]) >= p)[None, :]
+    x, stats = _hidden(params, batch, cfg, remat=remat, unroll=unroll,
+                       seq_shard=seq_shard)
     if ce == "streaming":
-        logits = forward(params, batch, cfg, remat=remat, unroll=unroll,
-                         head="raw", seq_shard=seq_shard)
-        nll = _streaming_ce(logits, labels, cfg.vocab)
+        nll = _streaming_ce(_head_raw(params, x, cfg), labels, cfg.vocab)
     else:  # "gather": the pre-§Perf baseline implementation
-        logits = forward(params, batch, cfg, remat=remat, unroll=unroll,
-                         seq_shard=seq_shard)
-        logits = logits.astype(jnp.float32)
+        logits = _head(params, x, cfg).astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
         nll = logz - gold
     if mask is None:
-        return jnp.mean(nll)
-    mask = jnp.broadcast_to(mask, nll.shape).astype(jnp.float32)
-    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        loss = jnp.mean(nll)
+    else:
+        mask = jnp.broadcast_to(mask, nll.shape).astype(jnp.float32)
+        loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    return (loss, stats) if with_stats else loss
+
+
+def _by_stack(per_stack: dict):
+    """One stack's cache as it is; several under their stacks' names."""
+    return next(iter(per_stack.values())) if len(per_stack) == 1 \
+        else per_stack
+
+
+def _scan_cached(blocks, x, body, unroll: bool, cache=None):
+    """Run body(bp, x[, cache_l]) -> (x, cache_l) over a stack; the
+    layers' caches come back stacked."""
+    if unroll:
+        n = jax.tree.leaves(blocks)[0].shape[0]
+        outs = []
+        for i in range(n):
+            args = [jax.tree.map(lambda a: a[i], blocks)]
+            if cache is not None:
+                args.append(jax.tree.map(lambda a: a[i], cache))
+            x, c = body(args[0], x, *args[1:])
+            outs.append(c)
+        return x, jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    if cache is None:
+        return lax.scan(lambda carry, bp: body(bp, carry), x, blocks)
+    return lax.scan(lambda carry, xs: body(xs[0], carry, xs[1]), x,
+                    (blocks, cache))
 
 
 def prefill(params, batch, cfg: ArchConfig, *, cache_len: int,
@@ -388,23 +474,13 @@ def prefill(params, batch, cfg: ArchConfig, *, cache_len: int,
         enc = encode(params, batch["frames"], cfg, remat=remat, unroll=unroll)
     x = _embed_inputs(params, batch, cfg, inputs)
     positions = _positions(cfg, b, s)
-
-    if unroll:
-        n = jax.tree.leaves(params["blocks"])[0].shape[0]
-        cache_list = []
-        for i in range(n):
-            bp = jax.tree.map(lambda a: a[i], params["blocks"])
-            x, cache = _block_prefill(bp, x, cfg, positions, enc, cache_len)
-            cache_list.append(cache)
-        caches = jax.tree.map(lambda *xs: jnp.stack(xs), *cache_list)
-    else:
-        def step(carry, bp):
-            y, cache = _block_prefill(bp, carry, cfg, positions, enc, cache_len)
-            return y, cache
-
-        x, caches = lax.scan(step, x, params["blocks"])
+    caches = {}
+    for name, blocks, c in _stacks(params, cfg):
+        x, caches[name] = _scan_cached(
+            blocks, x, partial(lambda bp, x, c: _block_prefill(
+                bp, x, c, positions, enc, cache_len), c=c), unroll)
     logits = _head(params, x[:, -1:], cfg)
-    return logits, caches
+    return logits, _by_stack(caches)
 
 
 # analysis: allow[ignored-argument] `params` keeps the cache constructor
@@ -412,8 +488,18 @@ def prefill(params, batch, cfg: ArchConfig, *, cache_len: int,
 def init_cache(params, cfg: ArchConfig, *, batch: int, cache_len: int,
                dtype=None):
     """Zero cache pytree with stacked layer axis (for serve_step lowering)."""
+    caches = {}
+    if cfg.first_dense_layers:
+        caches["dense_blocks"] = _init_stack_cache(
+            cfg, cfg.first_dense_layers, batch, cache_len, dtype)
+    caches["blocks"] = _init_stack_cache(
+        cfg, cfg.num_layers - cfg.first_dense_layers, batch, cache_len, dtype)
+    return _by_stack(caches)
+
+
+def _init_stack_cache(cfg: ArchConfig, l: int, b: int, cache_len: int,
+                      dtype):
     dtype = dtype or cfg.dtype
-    l, b = cfg.num_layers, batch
     kh, hd = cfg.num_kv_heads, cfg.head_dim
     window = cfg.sliding_window
     cap = min(cache_len, window) if window else cache_len
@@ -426,6 +512,11 @@ def init_cache(params, cfg: ArchConfig, *, batch: int, cache_len: int,
 
     if cfg.attention_mixer == "attn":
         cache: dict[str, Any] = {"mixer": attn_cache()}
+    elif cfg.attention_mixer == "mla":
+        cache = {"mixer": mixers.MLACache(
+            ckv=jnp.zeros((l, b, cache_len, cfg.kv_lora_rank), dtype),
+            kpe=jnp.zeros((l, b, cache_len, cfg.qk_rope_head_dim), dtype),
+        )}
     elif cfg.attention_mixer == "rwkv6":
         h = cfg.num_heads
         rhd = cfg.d_model // h
@@ -452,30 +543,20 @@ def decode_step(params, cache, tokens, pos, cfg: ArchConfig, *,
                 unroll: bool = False):
     """One decode step. tokens: (B, 1) int32; pos: () int32 absolute position.
 
-    cache leaves carry a leading layer axis; the layer stack is scanned with
-    the cache consumed/produced as scan xs/ys.
+    cache leaves carry a leading layer axis; each layer stack is scanned
+    with its cache consumed/produced as scan xs/ys.
     """
     b = tokens.shape[0]
     x = embed_tokens(tokens, params["embed"])
     if cfg.is_encdec:
         x = x + lax.dynamic_slice_in_dim(params["pos_embed"], pos, 1)[None]
     rope_pos = _decode_rope_positions(cfg, b, pos)
-
-    if unroll:
-        n = jax.tree.leaves(params["blocks"])[0].shape[0]
-        cache_list = []
-        for i in range(n):
-            bp = jax.tree.map(lambda a: a[i], params["blocks"])
-            cache_l = jax.tree.map(lambda a: a[i], cache)
-            x, nc = _block_decode(bp, x, cfg, cache_l, pos, rope_pos)
-            cache_list.append(nc)
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *cache_list)
-    else:
-        def step(carry, xs):
-            bp, cache_l = xs
-            y, new_cache = _block_decode(bp, carry, cfg, cache_l, pos, rope_pos)
-            return y, new_cache
-
-        x, new_cache = lax.scan(step, x, (params["blocks"], cache))
+    stacks = _stacks(params, cfg)
+    caches = cache if len(stacks) > 1 else {stacks[0][0]: cache}
+    new = {}
+    for name, blocks, c in stacks:
+        x, new[name] = _scan_cached(
+            blocks, x, partial(lambda bp, x, cl, c: _block_decode(
+                bp, x, c, cl, pos, rope_pos), c=c), unroll, caches[name])
     logits = _head(params, x, cfg)
-    return logits, new_cache
+    return logits, _by_stack(new)

@@ -23,6 +23,7 @@ ASSIGNED = {
     "qwen2-moe-a2.7b": (24, 2048, 16, 16, 1408, 151936),
     "whisper-medium": (24, 1024, 16, 16, 4096, 51865),
     "dbrx-132b": (40, 6144, 48, 8, 10752, 100352),
+    "moonlight-16b-a3b": (27, 2048, 16, 16, 1408, 163840),
 }
 
 
@@ -34,7 +35,7 @@ def test_assigned_hparams_exact(name):
 
 
 def test_all_ten_archs_present():
-    assert len(ARCH_NAMES) == 10
+    assert len(ARCH_NAMES) == 11
     assert set(ASSIGNED) == set(ARCH_NAMES)
 
 
@@ -52,7 +53,7 @@ def test_param_counts_in_expected_range():
         "stablelm-1.6b": 1.6e9, "deepseek-67b": 67e9, "rwkv6-7b": 7e9,
         "hymba-1.5b": 1.5e9, "starcoder2-15b": 15e9, "qwen2-vl-2b": 2e9,
         "qwen2.5-32b": 32e9, "qwen2-moe-a2.7b": 14e9, "whisper-medium": 0.7e9,
-        "dbrx-132b": 132e9,
+        "dbrx-132b": 132e9, "moonlight-16b-a3b": 16e9,
     }
     for name, target in expect.items():
         n = get_config(name).param_count()
@@ -98,15 +99,31 @@ def test_chip_share_cuts_only_the_listed_keys():
     import dataclasses
     import importlib
 
-    mod = importlib.import_module("repro.configs.stablelm_1_6b")
-    full, chip = get_config("stablelm-1.6b"), get_chip_config("stablelm-1.6b")
-    changed = {f.name for f in dataclasses.fields(full)
-               if getattr(full, f.name) != getattr(chip, f.name)}
-    assert changed == set(mod.REDUCED)
-    for key, (published, here) in mod.REDUCED.items():
-        assert (getattr(full, key), getattr(chip, key)) == (published, here)
-    assert (chip.d_model, chip.num_heads, chip.d_ff, chip.vocab) == (
-        2048, 32, 5632, 100352)
+    widths = {"stablelm-1.6b": ("stablelm_1_6b", (2048, 32, 5632, 100352)),
+              "moonlight-16b-a3b": ("moonlight_16b_a3b",
+                                    (2048, 16, 1408, 20480))}
+    for arch, (module, kept) in widths.items():
+        mod = importlib.import_module(f"repro.configs.{module}")
+        full, chip = get_config(arch), get_chip_config(arch)
+        changed = {f.name for f in dataclasses.fields(full)
+                   if getattr(full, f.name) != getattr(chip, f.name)}
+        assert changed == set(mod.REDUCED)
+        for key, (published, here) in mod.REDUCED.items():
+            assert (getattr(full, key), getattr(chip, key)) == (published,
+                                                                here)
+        assert (chip.d_model, chip.num_heads, chip.d_ff, chip.vocab) == kept
+    chip = get_chip_config("moonlight-16b-a3b")
+    # the router keeps its 64 outputs and 6 experts per token; MLA's ranks
+    # and the dense layer's width are as published
+    assert (chip.n_router, chip.experts_per_token, chip.kv_lora_rank,
+            chip.qk_nope_head_dim, chip.qk_rope_head_dim, chip.v_head_dim,
+            chip.dense_d_ff) == (64, 6, 512, 128, 64, 128, 11264)
+
+
+def test_moonlight_counts_sixteen_billion_with_three_active():
+    cfg = get_config("moonlight-16b-a3b")
+    assert 15.5e9 < cfg.param_count() < 16.5e9
+    assert 2.5e9 < cfg.active_param_count() < 3.5e9
 
 
 def test_chip_config_refuses_archs_without_a_cut():

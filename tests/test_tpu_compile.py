@@ -6,19 +6,24 @@ v5e:2x2 topology. The compiled program must hold the Mosaic kernel
 (`tpu_custom_call`). Interpret mode accepts block shapes and VMEM use that
 the chip's compiler refuses; these tests fail where the chip would.
 
-Shapes are row views of the chip-share config's leaves at the paper's
-k/d = 0.02: the embedding / LM head (100352, 2048) puts a 2000-row slab on
+The expert layer's grouped matmuls compile at Moonlight's chip-share
+widths. The wire's shapes are row views of the chip-share config's leaves
+at the paper's k/d = 0.02: the embedding / LM head (100352, 2048) puts a 2000-row slab on
 the wire, an MLP leaf (16384, 5632) a 320-row slab, and a single-layer MLP
 leaf (2048, 5632) a 40-row slab — five 8-row blocks, an odd count.
 The window write-back writes such slabs into whole leaves and slot rows.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
+from repro.configs import get_config, reduced
+from repro.core.dist import CompressedAggregation
 from repro.kernels.diana_shift import diana_shift_update
 from repro.kernels.pack import pack_slab, unpack_reduce, unpack_slab
 from repro.kernels.qsgd import qsgd_quantize
@@ -28,14 +33,16 @@ from repro.kernels.randk import (
     randk_decompress_into,
     randk_mask,
 )
+from repro.launch import steps
+from repro.models.moe import moe_gmm, moe_tgmm
 
 F32, BF16, U8 = jnp.float32, jnp.bfloat16, jnp.uint8
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2 topology. The compilation cache is
-    off meanwhile: a program compiled for a described chip is written to it
+def topology():
+    """A described v5e:2x2 topology. The compilation cache is off
+    meanwhile: a program compiled for a described chip is written to it
     but cannot be read back without the chip."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
@@ -49,8 +56,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    """One chip of the described topology."""
+    return SingleDeviceSharding(topology.devices[0])
 
 
 def compile_for_chip(fn, one_chip, *shapes):
@@ -144,3 +157,73 @@ def test_unpack_reduce(one_chip, k, d, nibble):
                                    nibble=nibble, interpret=False),
         one_chip, ((ranks, k // 2 if nibble else k, d), U8),
         ((ranks, k, 1), F32))
+
+
+# Moonlight's chip share: 2 x 8192 tokens x 6 copies sorted by expert, 8
+# held experts of width 1408 over d_model 2048, and one group of the rows
+# routed elsewhere. Each product in both orientations (gate/up: 2048 ->
+# 1408, down: 1408 -> 2048), as the forward pass and the input cotangent
+# run them, and the weight cotangents.
+ROWS, HELD = 2 * 8192 * 6, 8
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+@pytest.mark.parametrize("transpose", [False, True])
+def test_moe_gmm(one_chip, k, n, transpose):
+    rhs = (HELD, n, k) if transpose else (HELD, k, n)
+    compile_for_chip(
+        lambda x, w, gs: moe_gmm(x, w, gs, transpose_rhs=transpose),
+        one_chip, ((ROWS, k), BF16), (rhs, BF16),
+        ((HELD + 1,), jnp.int32))
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_moe_tgmm(one_chip, k, n):
+    compile_for_chip(
+        lambda x_t, g, gs: moe_tgmm(x_t, g, gs, HELD),
+        one_chip, ((k, ROWS), BF16), ((ROWS, n), BF16),
+        ((HELD + 1,), jnp.int32))
+
+
+def moonlight_step_hlo(topology, shape, monkeypatch):
+    """The reduced Moonlight train step (diana, shared f32 wire) compiled
+    for the described chips as a ("data", "model") mesh of `shape`, one
+    client per data rank, with the TPU's kernels."""
+    key = jax.random.key(0)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = reduced(get_config("moonlight-16b-a3b"), seq=128)
+    mesh = Mesh(np.asarray(topology.devices).reshape(shape),
+                ("data", "model"))
+    agg = CompressedAggregation(method="diana", wire="shared", fraction=0.02,
+                                mean_scale=1.0, shift_dtype=F32,
+                                wire_dtype="f32", wire_levels=None)
+    jitted, abstract, shardings, batch_sh = steps.make_train_step(
+        cfg, mesh, agg=agg, lr=0.02, remat="full")
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        abstract, shardings)
+    tokens = jax.ShapeDtypeStruct((2 * shape[0], 129), jnp.int32)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        tokens.shape, tokens.dtype,
+        sharding=batch_sh({"tokens": tokens})["tokens"])}
+    with jax.set_mesh(mesh):
+        return jitted.lower(state, batch, key).compile().as_text()
+
+
+def test_moonlight_clients_run_their_experts_on_their_own_chips(
+        topology, monkeypatch):
+    """Four clients on four chips: each chip runs its own client's expert
+    kernels, and the gradients gather no expert weights or token copies
+    from other chips."""
+    hlo = moonlight_step_hlo(topology, (4, 1), monkeypatch)
+    assert re.search(r"%moe_gmm[.\d]* = ", hlo)
+    assert re.search(r"%moe_tgmm[.\d]* = ", hlo)
+    gathers = [line for line in hlo.splitlines()
+               if "all-gather" in line and "client_grads" in line]
+    assert not gathers, gathers[:3]
+
+
+def test_moonlight_experts_split_over_model_are_refused(
+        topology, monkeypatch):
+    with pytest.raises(NotImplementedError, match="whole experts"):
+        moonlight_step_hlo(topology, (2, 2), monkeypatch)
