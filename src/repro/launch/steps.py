@@ -360,9 +360,9 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
     stack_pod = stack if pod_axis else (lambda t: t)
 
     def grads_and_loss(params_stacked, batch_c):
-        """Per-client (loss, grad, MoE row counts) under GSPMD: vmap over
-        the client dim. The counts, summed over the clients (`local_rows`)
-        or maxed (`max_expert_rows`), are {} without experts."""
+        """Per-client (loss, grad, MoE counts) under GSPMD: vmap over the
+        client dim. The counts, maxed over the clients (`max_expert_rows`)
+        or summed (the others), are {} without experts."""
         with jax.named_scope("client_grads"):
             if not moe_stats:
                 losses, g = jax.vmap(
@@ -373,8 +373,8 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
                 lambda p, b: jax.value_and_grad(loss_fn, has_aux=True)(p, b)
             )(params_stacked, batch_c)
             return losses, g, {
-                "moe_local_rows": jnp.sum(st["moe_local_rows"]),
-                "moe_max_expert_rows": jnp.max(st["moe_max_expert_rows"])}
+                k: (jnp.max if k == "moe_max_expert_rows" else jnp.sum)(v)
+                for k, v in st.items()}
 
     def broadcast_clients(tree):
         """params -> (M, *shape) client-stacked view (replication, no copy
@@ -565,8 +565,8 @@ def make_train_step(cfg: ArchConfig, mesh, *, agg: CompressedAggregation,
                   if debug_metrics else {})
         if stats:
             extras.update(
-                moe_local_rows=jnp.sum(stats["moe_local_rows"]),
-                moe_max_expert_rows=jnp.max(stats["moe_max_expert_rows"]))
+                {k: (jnp.max if k == "moe_max_expert_rows" else jnp.sum)(v)
+                 for k, v in stats.items()})
         return (direction, new_shifts, new_ms, new_psh, new_pms,
                 jnp.mean(losses), gnorm, extras)
 

@@ -498,15 +498,19 @@ def record_wire_paths(agg, params) -> None:
 
 def record_moe_rows(t: int, metrics: dict) -> None:
     """Counters `moe.local_rows` (copies routed to the experts this chip
-    holds, summed over the layers and clients) and `moe.max_expert_rows`
-    (the most any held expert got in a layer) of round `t`; MoE
-    configurations only. The values stay on the device until the sink's
-    writer reads them."""
+    holds, summed over the layers and clients), `moe.max_expert_rows`
+    (the most any held expert got in a layer) and `moe.expert_chunks`
+    (the chunks those copies ran in, summed over the layers and clients:
+    one a layer when the expert layer's block covers them) of round `t`;
+    MoE configurations only. The values stay on the device until the
+    sink's writer reads them."""
     if "moe_local_rows" in metrics:
         telemetry.counter("moe.local_rows", metrics["moe_local_rows"],
                           round=t)
         telemetry.counter("moe.max_expert_rows",
                           metrics["moe_max_expert_rows"], round=t)
+        telemetry.counter("moe.expert_chunks",
+                          metrics["moe_expert_chunks"], round=t)
 
 
 def main(argv=None):

@@ -5,10 +5,20 @@ d_ff is tensor-parallel over the "model" axis, and dispatch is a local sort
 of the client's token copies + grouped matmuls:
 
   1. router logits -> top-k experts + their weights per token
-  2. replicate the tokens k times and argsort the copies by expert id (a
-     local sort: the batch of one client lives on its chips)
-  3. one grouped matmul per FFN matrix — only the routed rows' FLOPs
-  4. unsort, weighted-sum over the k copies
+  2. argsort the b*s*k token copies by expert id, the held experts first
+     (a local sort: the batch of one client lives on its chips); the held
+     experts' copies are the first sum(counts) of that order
+  3. in chunks of C rows of that order (`chunk_rows`: twice the copies
+     the held experts expect, in whole 512-row tiles), as many as the held
+     copies need: gather the chunk's token rows, one grouped matmul per
+     FFN matrix over the chunk's share of each expert's group
+  4. add each row's output times its copy's weight into its token's row
+     (a scatter-add in float32)
+
+No array has a row for every copy: the copies of absent experts are never
+gathered. The chunk loop's trip count is dynamic, so the layer is a
+custom_vjp whose backward pass runs the same chunks, recomputing their
+products, and scatters the row cotangents back into the tokens.
 
 Routers (`ArchConfig.router`): "softmax" takes the top k of the softmax
 probabilities and renormalises them (qwen2-moe, dbrx). "sigmoid" is
@@ -28,12 +38,12 @@ The copies are sorted with the held experts first, so they are groups
 The grouped matmuls are `lax.ragged_dot_general` off the TPU. On the TPU
 they are megablox's Pallas kernels, `gmm` forward and for the input
 cotangent and `tgmm` for the weight cotangent, under the jit names
-`moe_gmm` and `moe_tgmm` so a device trace can find them; a trailing
-group of the copies of absent experts is never computed (only the held
-experts' tiles run) and comes back zero. GSPMD cannot split them: under
-a mesh of several chips each chip runs its own clients' products (a
-shard_map over the client axes), and a mesh that splits the experts'
-widths over "model" is refused.
+`moe_gmm` and `moe_tgmm` so a device trace can find them; a chunk's
+trailing rows past the held copies form one more group, which is never
+computed (only the held experts' tiles run) and comes back zero. GSPMD
+cannot split them: under a mesh of several chips each chip runs its own
+clients' products (a shard_map over the client axes), and a mesh that
+splits the experts' widths over "model" is refused.
 
 Qwen2-MoE's 4 shared experts (DeepSeek's 2) are folded into one dense FFN
 of width `shared_expert_ff` applied to every token (mathematically
@@ -338,35 +348,133 @@ def route(p, x, cfg: ArchConfig):
     return top_w, top_e, probs
 
 
+def chunk_rows(copies: int, held: int, n_router: int) -> int:
+    """C, the rows of one chunk of the held experts' copies: twice the
+    copies they expect (`copies` * held / n_router) in whole 512-row
+    tiles, and no more than every copy. A layer that holds every expert
+    takes all its copies in one chunk."""
+    return min(copies, 512 * math.ceil(2 * copies * held / n_router / 512))
+
+
+def _chunk(order, counts, i, c: int, k: int):
+    """Sorted rows i*c .. i*c + c - 1: (copy ids, their tokens, the held
+    experts' group sizes among them). Rows past the held copies, or past
+    the last copy, lie in no group, so their products come back zero."""
+    lo = i * c
+    copies = jnp.take(order, lo + jnp.arange(c), mode="clip")
+    ends = jnp.cumsum(counts)
+    gs = jnp.clip(jnp.minimum(ends, lo + c) - jnp.maximum(ends - counts, lo),
+                  0)
+    return copies, copies // k, gs.astype(jnp.int32)
+
+
+def _expert_ffn(xs, w_gate, w_up, w_down, gs, act: str):
+    """The held experts' FFN on rows sorted by expert, `gs` rows each;
+    rows past the groups come back zero."""
+    mm = partial(grouped_matmul, group_sizes=gs)
+    if act == "swiglu":
+        h = jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up)
+    else:
+        h = jax.nn.gelu(mm(xs, w_up))
+    return mm(h, w_down)
+
+
+@custom_batching.custom_vmap
+def _longest(n):
+    """n; under vmap the most over the batch, unbatched. The chunk loop
+    then runs as often as its longest member needs, with no select over
+    its carry: a member past its own chunks adds nothing."""
+    return n
+
+
+@_longest.def_vmap
+def _longest_vmap(axis_size, in_batched, n):
+    return (jnp.max(n) if in_batched[0] else n), False
+
+
+@functools.cache
+def _held_experts(c: int, k: int, act: str):
+    """(x (T, D), w_gate, w_up, w_down, w (T*k,) f32, order, counts) ->
+    (T, D): the sum over each token's copies routed to a held expert of
+    its weight times that expert's output. The copies sorted by `order`
+    run in chunks of `c` rows, as many as the held copies (sum(counts))
+    need; the backward pass recomputes each chunk's products."""
+
+    def contrib(xs, w_gate, w_up, w_down, wt, gs):
+        with jax.named_scope("moe_experts"):
+            ys = _expert_ffn(xs, w_gate, w_up, w_down, gs, act)
+        return ys.astype(jnp.float32) * wt[:, None]
+
+    def loop(body, counts, init):
+        chunks = _longest((jnp.sum(counts) + c - 1) // c)
+        return lax.fori_loop(0, chunks, body, init)
+
+    def forward(x, w_gate, w_up, w_down, w, order, counts):
+        def body(i, acc):
+            with jax.named_scope("moe_dispatch"):
+                copies, tok, gs = _chunk(order, counts, i, c, k)
+                xs, wt = x[tok], w[copies]
+            y = contrib(xs, w_gate, w_up, w_down, wt, gs)
+            with jax.named_scope("moe_dispatch"):
+                return acc.at[tok].add(y)
+        acc = loop(body, counts, jnp.zeros(x.shape, jnp.float32))
+        return acc.astype(x.dtype)
+
+    def fwd(*res):
+        return forward(*res), res
+
+    def bwd(res, dy):
+        x, w_gate, w_up, w_down, w, order, counts = res
+
+        def body(i, acc):
+            dx, dgate, dup, ddown, dw = acc
+            with jax.named_scope("moe_dispatch"):
+                copies, tok, gs = _chunk(order, counts, i, c, k)
+                xs, wt, dys = x[tok], w[copies], dy[tok]
+            _, vjp = jax.vjp(
+                lambda *a: contrib(*a, gs), xs, w_gate, w_up, w_down, wt)
+            dxs, g_gate, g_up, g_down, dwt = vjp(dys.astype(jnp.float32))
+            with jax.named_scope("moe_dispatch"):
+                return (dx.at[tok].add(dxs.astype(jnp.float32)),
+                        dgate + g_gate, dup + g_up, ddown + g_down,
+                        dw.at[copies].add(dwt))
+
+        f32 = lambda a: jnp.zeros(a.shape, jnp.float32)
+        dx, dgate, dup, ddown, dw = loop(
+            body, counts, tuple(map(f32, (x, w_gate, w_up, w_down, w))))
+        return (dx.astype(x.dtype), dgate.astype(w_gate.dtype),
+                dup.astype(w_up.dtype), ddown.astype(w_down.dtype), dw,
+                None, None)
+
+    held_experts = jax.custom_vjp(forward)
+    held_experts.defvjp(fwd, bwd)
+    return held_experts
+
+
 def moe_routed(p, x, cfg: ArchConfig, *, first_expert: int = 0):
     """The held experts' part of the routed result: x (B, S, D) -> (y
     (B, S, D), stats). The layer holds experts first_expert ..
     first_expert + num_experts - 1 of the router's n_router. stats:
-    `local_rows`, the copies routed to the held experts, and
-    `max_expert_rows`, the most any one of them got."""
+    `local_rows`, the copies routed to the held experts,
+    `max_expert_rows`, the most any one of them got, and
+    `expert_chunks`, the chunks of `chunk_rows` rows they ran in."""
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.experts_per_token
+    c = chunk_rows(b * s * k, e, cfg.n_router)
     with jax.named_scope("moe_router"):
         top_w, top_e, _ = route(p, x, cfg)
     with jax.named_scope("moe_dispatch"):
         # held experts first: copy ids relative to the first held expert
         flat_e = (top_e.reshape(b * s * k) - first_expert) % cfg.n_router
         order = jnp.argsort(flat_e)
-        inv = jnp.argsort(order)
-        xs = jnp.take(x.reshape(b * s, d), order // k, axis=0)
         counts = jnp.sum(jax.nn.one_hot(flat_e, e, dtype=jnp.int32), axis=0)
-    with jax.named_scope("moe_experts"):
-        mm = partial(grouped_matmul, group_sizes=counts)
-        if cfg.act == "swiglu":
-            h = jax.nn.silu(mm(xs, p["w_gate"])) * mm(xs, p["w_up"])
-        else:
-            h = jax.nn.gelu(mm(xs, p["w_up"]))
-        ys = mm(h, p["w_down"])
-    with jax.named_scope("moe_dispatch"):
-        yk = jnp.take(ys, inv, axis=0).reshape(b, s, k, d)
-        y = jnp.sum(yk * top_w[..., None].astype(yk.dtype), axis=2)
-    return y, {"local_rows": jnp.sum(counts),
-               "max_expert_rows": jnp.max(counts)}
+    y = _held_experts(c, k, cfg.act)(
+        x.reshape(b * s, d), p["w_gate"], p["w_up"], p["w_down"],
+        top_w.reshape(b * s * k), order, counts)
+    local = jnp.sum(counts)
+    return y.reshape(b, s, d), {"local_rows": local,
+                                "max_expert_rows": jnp.max(counts),
+                                "expert_chunks": (local + c - 1) // c}
 
 
 def moe_ffn(p, x, cfg: ArchConfig, *, return_aux: bool = False,

@@ -396,7 +396,9 @@ def _hidden(params, batch, cfg: ArchConfig, *, remat, unroll: bool,
             if aux:
                 stats = {"moe_local_rows": jnp.sum(aux["local_rows"]),
                          "moe_max_expert_rows": jnp.max(
-                             aux["max_expert_rows"])}
+                             aux["max_expert_rows"]),
+                         "moe_expert_chunks": jnp.sum(
+                             aux["expert_chunks"])}
     return x, stats
 
 

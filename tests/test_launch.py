@@ -368,3 +368,28 @@ def test_wire_path_counters():
                 window, 133 if window else 0)
             assert got[("wire.dense_leaves", level)] == (
                 2 - window, 0 if window else 133)
+
+
+def test_moe_row_counters():
+    """train.py records a MoE step's routing counts each round: the held
+    experts' copies, the most one expert got, and the chunks the copies
+    ran in; a dense step's metrics record none."""
+    from repro import telemetry
+    from repro.launch import train
+
+    metrics = {"moe_local_rows": jnp.int32(70626),
+               "moe_max_expert_rows": jnp.int32(4337),
+               "moe_expert_chunks": jnp.int32(5)}
+    sink = telemetry.install(telemetry.MetricsSink())
+    try:
+        train.record_moe_rows(3, metrics)
+        train.record_moe_rows(4, {"loss": jnp.float32(1.0)})
+    finally:
+        telemetry.uninstall()
+    events = [e for e in sink.events() if e["kind"] == "counter"]
+    sink.close()
+    assert telemetry.validate_events(events) == []
+    got = {e["name"]: (e["value"], e["round"]) for e in events}
+    assert got == {"moe.local_rows": (70626, 3),
+                   "moe.max_expert_rows": (4337, 3),
+                   "moe.expert_chunks": (5, 3)}

@@ -175,6 +175,57 @@ def test_held_share_is_the_references(params):
         axis=(0, 1, 2))))
 
 
+# 2 of the router's 16 experts held: 2 x 384 tokens x 2 copies = 1536
+# copies, 192 expected on the held experts, so a chunk is 512 rows
+SPARSE = dataclasses.replace(CFG, num_experts=2, router_experts=16)
+QWEN = dataclasses.replace(reduced(get_config("qwen2-moe-a2.7b")),
+                           dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("cfg,favoured", [
+    (SPARSE, ()),       # the expected load: one chunk
+    (SPARSE, (0,)),     # every token picks expert 0: two or three chunks
+    (SPARSE, (0, 1)),   # every copy held: three full chunks
+    (QWEN, ()),         # every expert held: one chunk of every copy
+], ids=["expected", "one-favoured", "all-held-copies", "qwen2-moe"])
+def test_chunked_experts_match_the_dense_reference(cfg, favoured):
+    """The held experts run on chunks of the sorted copies routed to them:
+    the output and the gradients of x, the router and the three expert
+    stacks are the dense oracle's, and `expert_chunks` counts the chunks
+    the held copies need."""
+    b, s = 2, 384
+    p = moe.init_moe(jax.random.key(12), cfg)
+    if "b_router" in p:
+        p["b_router"] = p["b_router"].at[jnp.array(favoured, int)].set(10.0)
+    x = jax.random.normal(jax.random.key(13), (b, s, cfg.d_model))
+    cot = jax.random.normal(jax.random.key(14), x.shape)
+    c = moe.chunk_rows(b * s * cfg.experts_per_token, cfg.num_experts,
+                       cfg.n_router)
+    assert c == (512 if cfg is SPARSE else b * s * cfg.experts_per_token)
+
+    def run(f):
+        def loss(p, x):
+            return jnp.sum(f(p, x) * cot)
+        y = f(p, x)
+        gp, gx = jax.jit(jax.grad(loss, argnums=(0, 1)))(p, x)
+        return y, gx, {k: gp[k] for k in ("router", "w_gate", "w_up",
+                                          "w_down")}
+
+    y, gx, gp = run(jax.jit(lambda p, x: moe.moe_ffn(p, x, cfg)))
+    y_ref, gx_ref, gp_ref = run(lambda p, x: moe.moe_ffn_ref(p, x, cfg))
+    assert rel(y, y_ref) < 1e-5
+    assert rel(gx, gx_ref) < 1e-5
+    for k in gp:
+        assert rel(gp[k], gp_ref[k]) < 1e-5, k
+    _, stats = moe.moe_ffn(p, x, cfg, with_stats=True)
+    _, top, _ = moe.route(p, x, cfg)
+    held = int(jnp.sum(top < cfg.num_experts))
+    assert int(stats["local_rows"]) == held
+    assert int(stats["expert_chunks"]) == -(-held // c)
+    want = {(): 1, (0,): (2, 3), (0, 1): 3}[favoured]
+    assert int(stats["expert_chunks"]) in np.atleast_1d(want)
+
+
 def test_loss_and_grads_match_reference(params):
     toks = tokens()
     lp, gp = jax.jit(jax.value_and_grad(
@@ -274,3 +325,51 @@ def test_kernel_path_through_the_training_loss(params, monkeypatch):
     # the kernels and ragged_dot sum in different orders
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4)
+
+
+def test_chunked_experts_kernel_path_matches_ragged_dot(monkeypatch):
+    """The Pallas kernels (interpret mode) on chunks that start inside a
+    group and end past the held copies, under the train step's client
+    vmap: the layer's output and gradients are ragged_dot's."""
+    p = moe.init_moe(jax.random.key(12), SPARSE)
+    p["b_router"] = p["b_router"].at[0].set(10.0)
+    x = jax.random.normal(jax.random.key(13), (1, 2, 384, SPARSE.d_model))
+    cot = jax.random.normal(jax.random.key(14), x.shape[1:])
+
+    def run():
+        loss = lambda p, x: jnp.sum(moe.moe_ffn(p, x, SPARSE) * cot)
+        return jax.jit(jax.vmap(jax.value_and_grad(loss, argnums=(0, 1)),
+                                in_axes=(None, 0)))(p, x)
+
+    want = run()
+    orig = moe.grouped_matmul
+    monkeypatch.setattr(moe, "grouped_matmul", lambda l, r, group_sizes,
+                        kernel=None, interpret=False: orig(
+                            l, r, group_sizes, kernel=True, interpret=True))
+    got = run()
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-4)
+
+
+def test_chunked_experts_clients_need_different_chunk_counts():
+    """Under the train step's client vmap the chunk loop runs as often as
+    its longest client needs: a client whose copies fit one chunk, beside
+    one that needs three, gets its own result and gradients."""
+    p = moe.init_moe(jax.random.key(12), SPARSE)
+    favoured = p["b_router"].at[:2].set(10.0)
+    ps = {**jax.tree.map(lambda a: jnp.stack([a, a]), p),
+          "b_router": jnp.stack([p["b_router"], favoured])}
+    x = jax.random.normal(jax.random.key(13), (2, 2, 384, SPARSE.d_model))
+
+    def run(p, x):
+        (y, stats), vjp = jax.vjp(
+            lambda p, x: moe.moe_ffn(p, x, SPARSE, with_stats=True), p, x)
+        return y, stats["expert_chunks"], vjp((x, jax.tree.map(
+            jnp.zeros_like, stats)))
+
+    batched = jax.jit(jax.vmap(run))(ps, x)
+    assert batched[1].tolist() == [1, 3]
+    for i in range(2):
+        one = jax.jit(run)(jax.tree.map(lambda a: a[i], ps), x[i])
+        for a, b in zip(jax.tree.leaves(batched), jax.tree.leaves(one)):
+            np.testing.assert_allclose(a[i], b, rtol=1e-5, atol=1e-4)

@@ -13,6 +13,8 @@ the wire, an MLP leaf (16384, 5632) a 320-row slab, and a single-layer MLP
 leaf (2048, 5632) a 40-row slab — five 8-row blocks, an odd count.
 The window write-back writes such slabs into whole leaves and slot rows.
 """
+import dataclasses
+import math
 import os
 import re
 
@@ -185,15 +187,17 @@ def test_moe_tgmm(one_chip, k, n):
         ((HELD + 1,), jnp.int32))
 
 
-def moonlight_step_hlo(topology, shape, monkeypatch):
+def moonlight_step_hlo(topology, shape, monkeypatch, *, held=4, seq=128):
     """The reduced Moonlight train step (diana, shared f32 wire) compiled
     for the described chips as a ("data", "model") mesh of `shape`, one
-    client per data rank, with the TPU's kernels."""
+    client per data rank of 2 x `seq` tokens, holding `held` of the
+    router's 8 experts, with the TPU's kernels."""
     key = jax.random.key(0)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = reduced(get_config("moonlight-16b-a3b"), seq=128)
-    mesh = Mesh(np.asarray(topology.devices).reshape(shape),
-                ("data", "model"))
+    cfg = dataclasses.replace(
+        reduced(get_config("moonlight-16b-a3b"), seq=seq), num_experts=held)
+    devices = np.asarray(topology.devices[:math.prod(shape)])
+    mesh = Mesh(devices.reshape(shape), ("data", "model"))
     agg = CompressedAggregation(method="diana", wire="shared", fraction=0.02,
                                 mean_scale=1.0, shift_dtype=F32,
                                 wire_dtype="f32", wire_levels=None)
@@ -202,7 +206,7 @@ def moonlight_step_hlo(topology, shape, monkeypatch):
     state = jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         abstract, shardings)
-    tokens = jax.ShapeDtypeStruct((2 * shape[0], 129), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((2 * shape[0], seq + 1), jnp.int32)
     batch = {"tokens": jax.ShapeDtypeStruct(
         tokens.shape, tokens.dtype,
         sharding=batch_sh({"tokens": tokens})["tokens"])}
@@ -221,6 +225,23 @@ def test_moonlight_clients_run_their_experts_on_their_own_chips(
     gathers = [line for line in hlo.splitlines()
                if "all-gather" in line and "client_grads" in line]
     assert not gathers, gathers[:3]
+
+
+def test_moonlight_step_runs_its_experts_on_a_block_of_the_copies(
+        topology, monkeypatch):
+    """One expert of the router's 8 held, 2 x 256 tokens x 2 copies: the
+    held experts' copies run in 512-row chunks, and nothing the expert
+    layer computes has a row for every one of the 1024 copies."""
+    hlo = moonlight_step_hlo(topology, (1, 1), monkeypatch, held=1, seq=256)
+    kernels = [line for line in hlo.splitlines()
+               if re.search(r"%moe_t?gmm[.\d]* = ", line)]
+    assert kernels and all("client_grads" in line for line in kernels)
+    moe = [line for line in hlo.splitlines()
+           if re.search(r'op_name="[^"]*moe_(dispatch|experts)', line)]
+    assert any(re.search(r"\[(1,)?512,128\]", line) for line in moe)
+    copies = [line for line in moe
+              if re.search(r"\[(1,)?1024,(128|256)\]", line)]
+    assert not copies, copies[:3]
 
 
 def test_moonlight_experts_split_over_model_are_refused(
